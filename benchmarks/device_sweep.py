@@ -37,6 +37,7 @@ from repro.core import (ComputeMode, IMPL_PALLAS, PlannerConfig, plan_network,
                         run_network, synthesize)
 from repro.device import DeviceProfile, registered_profiles
 from repro.serving import ProgramCache
+from repro.launch.compile_cache import enable_compile_cache
 
 from .bench_schema import SCHEMA_VERSION, write_bench
 from .common import csv_row
@@ -152,6 +153,7 @@ def run(reps: int = 0) -> List[str]:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dry-run", action="store_true",
                     help="small network + tiny calibration set: validates "

@@ -36,6 +36,7 @@ from repro.cnn import WORKLOADS, init_network_params
 from repro.core import (ComputeMode, DispatchStats, execute_graph,
                         lower_network, mode_tolerance, plan_network,
                         run_network)
+from repro.launch.compile_cache import enable_compile_cache
 
 from .bench_schema import SCHEMA_VERSION, write_bench
 from .common import bench, csv_row
@@ -159,6 +160,7 @@ def run(reps: int = 4) -> List[str]:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dry-run", action="store_true",
                     help="small networks + minimal reps: validates the "

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from repro.cnn import WORKLOADS, init_network_params
 from repro.core import (ComputeMode, DispatchStats, execute_graph,
                         mode_tolerance, synthesize)
+from repro.launch.compile_cache import enable_compile_cache
 
 from .bench_schema import SCHEMA_VERSION, write_bench
 from .common import bench, csv_row
@@ -168,6 +169,7 @@ def run(reps: int = 4) -> List[str]:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dry-run", action="store_true",
                     help="small networks + minimal reps: validates the "

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.cnn import squeezenet, init_network_params
 from repro.core import IMPL_DEFAULT, ComputeMode, run_network, synthesize
 from repro.data.synthetic import imagenet_like
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import csv_row
 
@@ -62,4 +63,5 @@ def run(n_val: int = 64):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

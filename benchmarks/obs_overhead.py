@@ -32,6 +32,7 @@ from repro.obs import (MetricsRegistry, Tracer, measure_drift, render_table,
                        write_metrics_json, write_trace_jsonl)
 from repro.serving import ReplicaSet, ServingConfig
 from repro.serving.loadgen import warm_replicas
+from repro.launch.compile_cache import enable_compile_cache
 
 from .bench_schema import SCHEMA_VERSION, write_bench
 
@@ -121,6 +122,7 @@ def run(net_name: str = "squeezenet", *, scale: float = 0.08,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", "--dry-run", dest="smoke", action="store_true",
                     help="tiny fast configuration for CI")

@@ -2,44 +2,53 @@
 
 ``PYTHONPATH=src python -m benchmarks.run [--fast]``
 prints ``name,us_per_call,derived`` CSV rows.
+
+Each suite runs in a child process of its own and the parent never imports
+JAX: a chip belongs to one process at a time, and ``warmstart_speedup``
+itself starts two children that need it.
 """
 from __future__ import annotations
 
+import subprocess
 import sys
 import traceback
 
+SUITES = ("table1_speedup", "table2_energy_proxy", "table3_vs_klp_flp",
+          "mode_selection", "device_sweep", "fusion_speedup", "int8_speedup",
+          "warmstart_speedup", "roofline", "dryrun_summary")
+
+
+def run_suite(name: str, reps: int) -> None:
+    """Child mode: print one suite's CSV rows."""
+    import importlib
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    mod = importlib.import_module(f"benchmarks.{name}")
+    if name == "warmstart_speedup":
+        rows = mod.rows()
+    elif name in ("mode_selection", "roofline", "dryrun_summary"):
+        rows = mod.run()
+    else:
+        rows = mod.run(reps=reps)
+    for row in rows:
+        print(row, flush=True)
+
 
 def main() -> None:
-    fast = "--fast" in sys.argv
-    reps = 4 if fast else 8
-    from . import (device_sweep, fusion_speedup, int8_speedup, mode_selection,
-                   table1_speedup, table2_energy_proxy, table3_vs_klp_flp,
-                   warmstart_speedup)
-    suites = [
-        ("table1_speedup", lambda: table1_speedup.run(reps=reps)),
-        ("table2_energy_proxy", lambda: table2_energy_proxy.run(reps=reps)),
-        ("table3_vs_klp_flp", lambda: table3_vs_klp_flp.run(reps=reps)),
-        ("mode_selection", lambda: mode_selection.run()),
-        ("device_sweep", lambda: device_sweep.run(reps=reps)),
-        ("fusion_speedup", lambda: fusion_speedup.run(reps=reps)),
-        ("int8_speedup", lambda: int8_speedup.run(reps=reps)),
-        ("warmstart_speedup", warmstart_speedup.rows),
-    ]
-    try:
-        from . import dryrun_summary, roofline
-        suites.append(("roofline", roofline.run))
-        suites.append(("dryrun_summary", dryrun_summary.run))
-    except ImportError:
-        pass
-    print("name,us_per_call,derived")
-    failed = []
-    for name, fn in suites:
+    reps = 4 if "--fast" in sys.argv else 8
+    if "--suite" in sys.argv:
         try:
-            for row in fn():
-                print(row, flush=True)
-        except Exception:  # keep going; report at the end
-            failed.append(name)
+            run_suite(sys.argv[sys.argv.index("--suite") + 1], reps)
+        except Exception:
             traceback.print_exc()
+            sys.exit(1)
+        return
+    print("name,us_per_call,derived", flush=True)
+    failed = [name for name in SUITES
+              if subprocess.run([sys.executable, "-m", "benchmarks.run",
+                                 "--suite", name, *sys.argv[1:]]).returncode]
     if failed:
         print(f"# FAILED suites: {failed}", file=sys.stderr)
         sys.exit(1)

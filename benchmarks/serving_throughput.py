@@ -28,6 +28,7 @@ from repro.core import ComputeMode, synthesize
 from repro.obs import (MetricsRegistry, Tracer, measure_drift, render_table,
                        write_metrics_json, write_trace_jsonl)
 from repro.serving import DISPATCH_POLICIES, ServingConfig, run_offered_load
+from repro.launch.compile_cache import enable_compile_cache
 
 from .bench_schema import SCHEMA_VERSION, write_bench
 
@@ -127,6 +128,7 @@ def run(net_name: str = "squeezenet", *, scale: float = 0.08,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", "--dry-run", dest="smoke", action="store_true",
                     help="tiny fast configuration for CI")

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.cnn import WORKLOADS, init_network_params
 from repro.core import ComputeMode, ExecutionPlan, run_network, synthesize
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import bench, csv_row
 
@@ -49,4 +50,5 @@ def run(reps: int = 8):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.cnn import squeezenet, init_network_params
 from repro.core import ComputeMode, ExecutionPlan, run_network, synthesize
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import bench, csv_row
 
@@ -40,4 +41,5 @@ def run(reps: int = 8):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

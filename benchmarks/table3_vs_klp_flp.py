@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.cnn import alexnet, init_network_params
 from repro.core import (ComputeMode, ExecutionPlan, Parallelism, plan_network,
                         run_network, synthesize)
+from repro.launch.compile_cache import enable_compile_cache
 
 from .bench_schema import SCHEMA_VERSION, write_bench
 from .common import bench, csv_row
@@ -140,6 +141,7 @@ def to_bench_doc(pairs: List[Tuple[str, float]], synthesis: dict,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dry-run", action="store_true",
                     help="minimal reps: validates the pipeline + schema, "

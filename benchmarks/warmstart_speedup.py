@@ -40,6 +40,8 @@ import tempfile
 import time
 from typing import Dict
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .bench_schema import SCHEMA_VERSION, write_bench
 
 #: stdout marker a phase child prints its result JSON behind.
@@ -54,7 +56,7 @@ def run_phase(artifact_dir: str, *, net_name: str, scale: float,
     import jax
     import jax.numpy as jnp
 
-    from repro.artifacts import ArtifactStore, executables_supported
+    from repro.artifacts import ArtifactStore
     from repro.cnn import WORKLOADS, init_network_params
     from repro.core import run_network, synthesize
     from repro.obs import MetricsRegistry
@@ -100,7 +102,6 @@ def run_phase(artifact_dir: str, *, net_name: str, scale: float,
         + count("artifact_writes_total", kind="executable"),
         "artifact_invalid": count("artifact_invalid_total", kind="program")
         + count("artifact_invalid_total", kind="executable"),
-        "executables_supported": int(executables_supported()),
         "fingerprint": program.fingerprint(),
         "backend": jax.default_backend(),
     }
@@ -142,8 +143,7 @@ def run(args) -> Dict:
             f"cold converged to {cold['fingerprint']} — the store returned "
             "a different program")
 
-    plan_only = int(warm["stage_d_compiles"] > 0
-                    or not warm["executables_supported"])
+    plan_only = int(warm["stage_d_compiles"] > 0)
     return {
         "benchmark": "warmstart_speedup",
         "schema_version": SCHEMA_VERSION,
@@ -199,6 +199,7 @@ def rows(out: str = "BENCH_warmstart.json"):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", "--dry-run", dest="smoke", action="store_true",
                     help="tiny fast configuration for CI")

@@ -7,7 +7,7 @@ program fingerprint.  ``synthesize(artifact_store=...)`` and the serving
 tier's :class:`~repro.serving.program_cache.ProgramCache` use it to skip
 the fixed-point loop and Stage-D compiles on restart (DESIGN.md §13).
 """
-from .codec import ArtifactCodecError, executables_supported
+from .codec import ArtifactCodecError
 from .store import (ARTIFACT_SCHEMA_VERSION, ArtifactError, ArtifactStore,
                     synthesis_request_key)
 
@@ -16,6 +16,5 @@ __all__ = [
     "ArtifactCodecError",
     "ArtifactError",
     "ArtifactStore",
-    "executables_supported",
     "synthesis_request_key",
 ]

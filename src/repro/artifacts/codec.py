@@ -442,17 +442,6 @@ def stamp_matches(meta: Dict[str, Any]) -> Tuple[bool, str]:
     return True, ""
 
 
-def executables_supported(program: Optional[SynthesizedProgram] = None
-                          ) -> bool:
-    """Cheap capability probe: can this build serialize executables at all?
-    (Per-program failures still degrade case by case.)"""
-    try:
-        from jax import export as jax_export  # noqa: F401
-    except ImportError:
-        return False
-    return True
-
-
 __all__ = [
     "ArtifactCodecError",
     "decode_graph", "decode_layer", "decode_layer_plan", "decode_mode_report",
@@ -461,6 +450,6 @@ __all__ = [
     "encode_graph", "encode_layer", "encode_layer_plan", "encode_mode_report",
     "encode_network", "encode_plan", "encode_program",
     "encode_synthesis_report", "encode_weights",
-    "executable_stamp", "executables_supported", "export_executable",
+    "executable_stamp", "export_executable",
     "hydrate_executable", "stamp_matches",
 ]

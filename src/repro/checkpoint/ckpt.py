@@ -18,19 +18,14 @@ import numpy as np
 SEP = "/"
 
 
-def _key_name(p) -> str:
-    """Bare name of one path entry (what keystr(simple=True) returns on
-    newer JAX; spelled out here to support older tree_util APIs too)."""
-    for attr in ("key", "idx", "name"):
-        if hasattr(p, attr):
-            return str(getattr(p, attr))
-    return str(p)
+def _key(path) -> str:
+    return jax.tree_util.keystr(path, simple=True, separator=SEP)
 
 
 def _flatten(tree) -> dict:
     flat = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        key = SEP.join(_key_name(p) for p in path)
+        key = _key(path)
         flat[key] = np.asarray(jax.device_get(leaf))
     return flat
 
@@ -54,7 +49,7 @@ def load_checkpoint(path: str, target_tree, *,
                     else [None] * len(leaves_p))
     out = []
     for (path_k, leaf), shard in zip(leaves_p, shard_leaves):
-        key = SEP.join(_key_name(p) for p in path_k)
+        key = _key(path_k)
         if key not in flat:
             raise KeyError(f"checkpoint missing {key}")
         arr = flat[key]

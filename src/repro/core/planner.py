@@ -477,10 +477,9 @@ def autotune_plan(net: NetworkDescription, params, x: jnp.ndarray,
             else:
                 run = jax.jit(lambda a, l=l, cand=cand: apply_layer(
                     l, cand, params.get(l.name), [a]))
-            try:
-                timings.append((_time_fn(lambda: run(x_in), reps), impl))
-            except Exception:      # candidate can't run this shape; skip it
-                continue
+            # A candidate that fails to compile or run raises: a kernel
+            # the chip refuses must never turn into a silent XLA pick.
+            timings.append((_time_fn(lambda: run(x_in), reps), impl))
         if not timings:
             continue
         t_best, impl_best = min(timings)

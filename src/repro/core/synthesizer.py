@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..device import DeviceProfile, resolve_profile
+from ..device import DeviceProfile, profile_for_device, resolve_profile
 from ..obs import MetricsRegistry, Tracer
 from .graph import lower_network
 from .layout import LANES, weights_to_map_major
@@ -89,6 +89,11 @@ class BatchProgram:
     plan_fingerprint: str
     compile_seconds: float
     _compiled: Callable[[jnp.ndarray], jnp.ndarray]
+
+    def hlo_text(self) -> str:
+        """The optimized HLO of the compiled executable — what the device
+        runs (a compiled Pallas launch shows as a ``tpu_custom_call``)."""
+        return self._compiled.as_text()
 
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         if tuple(x.shape) != self.input_shape:
@@ -161,19 +166,25 @@ class SynthesizedProgram:
             self._infer = jax.jit(self._forward)
         return self._infer
 
-    def for_batch(self, batch: int) -> BatchProgram:
+    def for_batch(self, batch: int, device=None) -> BatchProgram:
         """Stage D alone: AOT-compile this program for a fixed batch size.
 
         Stages A–C are already done — this re-specializes the *same* plan
         and prepared weights for a new leading dimension, which is exactly
-        what the serving layer's power-of-two buckets need.
+        what the serving layer's power-of-two buckets need.  ``device``
+        (a JAX device) compiles the executable for that device: it runs
+        there, with the prepared weights it carries as constants; None
+        means the default device.
         """
         if batch < 1:
             raise ValueError(f"batch must be >= 1, got {batch}")
         shape = (batch, *self.net.input_shape)
+        sharding = (jax.sharding.SingleDeviceSharding(device)
+                    if device is not None else None)
         t0 = time.time()
         compiled = jax.jit(self._forward).lower(
-            jax.ShapeDtypeStruct(shape, self.input_dtype)).compile()
+            jax.ShapeDtypeStruct(shape, self.input_dtype,
+                                 sharding=sharding)).compile()
         self.stage_d_compiles += 1
         return BatchProgram(batch=batch, input_shape=shape,
                             plan_fingerprint=self.plan.fingerprint(),
@@ -373,10 +384,11 @@ def synthesize(net: NetworkDescription,
     or let the planner build it.  ``device=`` selects the synthesis target —
     a :class:`~repro.device.DeviceProfile`, a registry name (``"tpu_v4"``),
     or ``"auto"`` (calibrated/cached profile for this host, deterministic
-    builtin fallback off-TPU); every cost rule and the plan fingerprint are
-    taken under that device.  (The PR-1 ``backend=``/``parallelism=``
-    global flags were removed in PR 7 — pass an equivalent
-    ``plan=ExecutionPlan.uniform(...)`` instead.)
+    builtin fallback off-TPU); with none of ``device=``, ``plan=`` and
+    ``planner_config=`` the target is this process's chip, looked up by its
+    device kind (:func:`~repro.device.profile_for_device`).  Every cost
+    rule and the plan fingerprint are taken under that device.  A uniform
+    backend is ``plan=ExecutionPlan.uniform(...)``.
 
     With a validation set, Stages A and C run as a **fixed-point loop**
     (plan -> probe -> re-plan, ``max_iterations`` cap, deterministic
@@ -447,7 +459,10 @@ def synthesize(net: NetworkDescription,
                 "or drop one of the arguments")
         planner_config = dataclasses.replace(planner_config or PlannerConfig(),
                                              profile=profile)
-    elif planner_config is None and plan is not None:
+    elif planner_config is None and plan is None:
+        # No target named: plan for the chip this process runs on.
+        planner_config = PlannerConfig(profile=profile_for_device())
+    elif planner_config is None:
         # Keep the supplied plan's device sticky through re-planning.
         planner_config = PlannerConfig(profile=plan.profile)
     elif (plan is not None and planner_config is not None
@@ -469,11 +484,9 @@ def synthesize(net: NetworkDescription,
     store_request_key: Optional[str] = None
     if artifact_store is not None and plan is None:
         from ..artifacts.store import synthesis_request_key
-        key_profile = (planner_config.profile if planner_config is not None
-                       else PlannerConfig().profile)
         store_request_key = synthesis_request_key(
             net, params, validation=validation,
-            device_identity=key_profile.identity(),
+            device_identity=planner_config.profile.identity(),
             max_degradation=max_degradation, allow_int8=allow_int8,
             forced_mode=forced_mode, fuse=fuse, autotune=autotune,
             max_iterations=max_iterations)

@@ -13,15 +13,16 @@ from .calibrate import (cache_key, calibrate, default_cache_dir,
                         measure_stream_bandwidth, measurement_available,
                         resolve_profile, store_cached_profile)
 from .profile import (CPU_INTERPRET, DEFAULT_PROFILE, LANE_WIDTH,
-                      PROFILE_SCHEMA_VERSION, TPU_V4, TPU_V5E, DeviceProfile,
-                      ProfileSchemaError, get_profile, register_profile,
+                      PROFILE_SCHEMA_VERSION, TPU_PROFILES_BY_KIND, TPU_V4,
+                      TPU_V5E, DeviceProfile, ProfileSchemaError, get_profile,
+                      profile_for_device, register_profile,
                       registered_profiles)
 
 __all__ = [
     "CPU_INTERPRET", "DEFAULT_PROFILE", "LANE_WIDTH",
-    "PROFILE_SCHEMA_VERSION", "TPU_V4", "TPU_V5E", "DeviceProfile",
-    "ProfileSchemaError", "get_profile", "register_profile",
-    "registered_profiles",
+    "PROFILE_SCHEMA_VERSION", "TPU_PROFILES_BY_KIND", "TPU_V4", "TPU_V5E",
+    "DeviceProfile", "ProfileSchemaError", "get_profile",
+    "profile_for_device", "register_profile", "registered_profiles",
     "cache_key", "calibrate", "default_cache_dir", "load_cached_profile",
     "measure_matmul_flops", "measure_stream_bandwidth",
     "measurement_available", "resolve_profile", "store_cached_profile",

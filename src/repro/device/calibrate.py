@@ -23,7 +23,7 @@ deterministic under test (a stubbed clock yields exact, repeatable rates).
 measurement is unavailable — any non-TPU backend, i.e. CPU CI, where
 timing the interpreter would calibrate the *simulator* — it falls back to
 the builtin registry deterministically instead (``cpu_interpret`` off-TPU,
-``tpu_v5e`` otherwise).
+the chip's builtin by device kind otherwise).
 
 CLI (used by CI to produce and validate a profile artifact):
 
@@ -41,8 +41,8 @@ from typing import Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from .profile import (CPU_INTERPRET, TPU_V5E, DeviceProfile,
-                      ProfileSchemaError, get_profile)
+from .profile import (CPU_INTERPRET, DeviceProfile, ProfileSchemaError,
+                      get_profile, profile_for_device)
 
 Clock = Callable[[], float]
 
@@ -122,7 +122,7 @@ def calibrate(base: Optional[DeviceProfile] = None, *,
     host's real integer throughput rather than a datasheet ratio.
     """
     if base is None:
-        base = TPU_V5E if jax.default_backend() == "tpu" else CPU_INTERPRET
+        base = _builtin_for_host()
     bf16 = measure_matmul_flops(jnp.bfloat16, sizes=sizes, reps=reps,
                                 clock=clock, seed=seed)
     f32 = measure_matmul_flops(jnp.float32, sizes=sizes, reps=reps,
@@ -236,7 +236,15 @@ def resolve_profile(device: "str | DeviceProfile | None" = None, *,
         if use_cache:
             store_cached_profile(profile, cache_dir)
         return profile
-    return TPU_V5E if jax.default_backend() == "tpu" else CPU_INTERPRET
+    return _builtin_for_host()
+
+
+def _builtin_for_host() -> DeviceProfile:
+    """This host's builtin: the chip's profile by device kind on a TPU,
+    ``cpu_interpret`` elsewhere."""
+    if jax.default_backend() == "tpu":
+        return profile_for_device()
+    return CPU_INTERPRET
 
 
 def main() -> int:

@@ -226,10 +226,40 @@ CPU_INTERPRET = DeviceProfile(
     supports_pallas=False,            # Pallas TPU kernels only interpret here
     description="CPU host (CI): XLA-only, Pallas in interpret mode")
 
-#: What the pipeline assumes when no device is named — the historical
-#: hard-coded target, so default plans and fingerprints stay v5e-shaped
-#: on every host.
+#: What the pipeline assumes when no device is named off-TPU — the
+#: historical hard-coded target, so default plans and fingerprints on a
+#: test host stay v5e-shaped (Pallas only interprets there, so the planner
+#: keeps XLA).  On a TPU the chip itself is the target:
+#: :func:`profile_for_device`.
 DEFAULT_PROFILE = TPU_V5E
+
+#: The builtin profile of each TPU generation, keyed by ``device_kind`` as
+#: JAX reports it.
+TPU_PROFILES_BY_KIND: Dict[str, DeviceProfile] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v4": TPU_V4,
+}
+
+
+def profile_for_device(device: Any = None) -> DeviceProfile:
+    """The builtin profile for a JAX device (default: ``jax.devices()[0]``).
+
+    A TPU is looked up by its ``device_kind`` in
+    :data:`TPU_PROFILES_BY_KIND`; a TPU kind missing from the table is an
+    error, never silently planned as a v5e.  Any other platform plans for
+    :data:`DEFAULT_PROFILE`.
+    """
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return DEFAULT_PROFILE
+    try:
+        return TPU_PROFILES_BY_KIND[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no device profile for TPU kind {device.device_kind!r}; known "
+            f"kinds: {', '.join(sorted(TPU_PROFILES_BY_KIND))}") from None
 
 _REGISTRY: Dict[str, DeviceProfile] = {}
 

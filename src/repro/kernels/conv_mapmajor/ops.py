@@ -1,7 +1,6 @@
 """Jit'd public wrapper for the map-major OLP conv kernel.
 
-Handles the NCHW <-> map-major boundary, SAME/VALID padding (including the
-stride-halo rows the kernel's slice-reshape trick needs), channel-group
+Handles the NCHW <-> map-major boundary, SAME/VALID padding, channel-group
 padding, and the VMEM envelope check with an XLA fallback.
 
 Registers itself as the ``pallas_mapmajor`` conv implementation in the
@@ -24,14 +23,14 @@ from ...core.precision import (ComputeMode, QParams, QuantizedTensor,
                                fake_quantize_act, quantize_act_int8,
                                resolve_weight)
 from ...device.profile import DEFAULT_PROFILE
-from .conv_mapmajor import conv_mapmajor, conv_mapmajor_int8
+from .conv_mapmajor import conv_mapmajor, conv_mapmajor_int8, conv_vmem_bytes
 from .ref import pack_weights
 
-# Per-block VMEM budget for the input block (bytes); above it we fall back.
-# The number lives in the device profile (repro.device); this module-level
-# name is the default-profile value, kept as the runtime guard's budget and
-# as a legacy alias.  Planning against another device passes its profile's
-# budget to :func:`fits_vmem` explicitly.
+# Scoped-VMEM budget for one grid step of the kernel (bytes); above it we
+# fall back.  The number lives in the device profile (repro.device); this
+# module-level name is the default-profile value, kept as the runtime
+# guard's budget and as a legacy alias.  Planning against another device
+# passes its profile's budget to :func:`fits_vmem` explicitly.
 VMEM_INPUT_BUDGET = DEFAULT_PROFILE.vmem_budget
 
 
@@ -45,9 +44,7 @@ def _pad_amounts(h, k, s, padding):
     needed = (out - 1) * s + k
     before = (max(needed - h, 0) // 2) if padding == "SAME" else 0
     after = max(needed - h - before, 0)
-    # halo for the kernel's strided slice-reshape trick
-    halo = (s - 1) if s > 1 else 0
-    return out, before, after + halo
+    return out, before, after
 
 
 def _pack_bias(b: jnp.ndarray, cout: int, u: int) -> jnp.ndarray:
@@ -65,9 +62,9 @@ def _pack_bias(b: jnp.ndarray, cout: int, u: int) -> jnp.ndarray:
 def _conv2d_mapmajor_pallas(x: jnp.ndarray, w: jnp.ndarray, b=None, *,
                             stride: int = 1, padding: str = "SAME",
                             mode: ComputeMode = ComputeMode.RELAXED,
-                            u: int = LANES, interpret: bool = True,
+                            u: int = LANES, interpret: Optional[bool] = None,
                             fuse_bias_relu: bool = False) -> jnp.ndarray:
-    n, cin, h, wdim = x.shape
+    _, _, h, wdim = x.shape
     cout, _, kh, kw = w.shape
     h_out, ph0, ph1 = _pad_amounts(h, kh, stride, padding)
     w_out, pw0, pw1 = _pad_amounts(wdim, kw, stride, padding)
@@ -97,7 +94,8 @@ def _conv2d_mapmajor_pallas(x: jnp.ndarray, w: jnp.ndarray, b=None, *,
                                              "interpret", "fuse_bias_relu"))
 def _conv2d_mapmajor_pallas_int8(x, wq, wscale, act_scale, b=None, *,
                                  stride: int = 1, padding: str = "SAME",
-                                 u: int = LANES, interpret: bool = True,
+                                 u: int = LANES,
+                                 interpret: Optional[bool] = None,
                                  fuse_bias_relu: bool = False) -> jnp.ndarray:
     """True int8 dispatch: quantize activations at the calibrated static
     scale, launch the int8 x int8 -> int32 kernel, dequant at flush.
@@ -105,7 +103,7 @@ def _conv2d_mapmajor_pallas_int8(x, wq, wscale, act_scale, b=None, *,
     ``wq`` is the prepared int8 weight payload (OIHW), ``wscale`` its
     per-output-channel f32 scales, ``act_scale`` the layer's per-tensor
     activation scale (a traced f32 scalar — calibration never retraces).
-    The zero padding added for SAME/halo is exact under symmetric
+    The zero padding added for SAME is exact under symmetric
     quantization (zero_point = 0 maps to int8 zero), so it is applied
     after quantization at no accuracy cost.
     """
@@ -132,7 +130,7 @@ def _conv2d_mapmajor_pallas_int8(x, wq, wscale, act_scale, b=None, *,
 
 def conv2d_mapmajor_int8(x: jnp.ndarray, w: QuantizedTensor, qp: QParams,
                          b=None, *, stride: int = 1, padding: str = "SAME",
-                         u: int = LANES, interpret: bool = True,
+                         u: int = LANES, interpret: Optional[bool] = None,
                          vmem_budget: Optional[int] = None,
                          fuse_bias_relu: bool = False) -> jnp.ndarray:
     """NCHW int8-datapath conv: int8 operands, int32 accumulation, fused
@@ -161,7 +159,7 @@ def conv2d_mapmajor_int8(x: jnp.ndarray, w: QuantizedTensor, qp: QParams,
 def conv2d_mapmajor(x: jnp.ndarray, w: jnp.ndarray, b=None, *,
                     stride: int = 1, padding: str = "SAME",
                     mode: ComputeMode = ComputeMode.RELAXED,
-                    u: int = LANES, interpret: bool = True,
+                    u: int = LANES, interpret: Optional[bool] = None,
                     vmem_budget: Optional[int] = None,
                     fuse_bias_relu: bool = False) -> jnp.ndarray:
     """NCHW in, NCHW out; map-major + Pallas OLP inside.
@@ -171,9 +169,9 @@ def conv2d_mapmajor(x: jnp.ndarray, w: jnp.ndarray, b=None, *,
     (the fused-group epilogue): one Pallas launch computes
     ``relu(conv(x, w) + b)``.
 
-    Enforces the kernel's VMEM envelope: when one channel group's padded
-    input plane exceeds ``vmem_budget`` (the target device's block budget;
-    defaults to :data:`VMEM_INPUT_BUDGET`), the layer runs on the
+    Enforces the kernel's VMEM envelope: when one grid step would exceed
+    ``vmem_budget`` (the target device's scoped-VMEM budget; defaults to
+    :data:`VMEM_INPUT_BUDGET`), the layer runs on the
     fused-XLA OLP path instead (same semantics, no VMEM ceiling).  The
     planned dispatch path passes the plan's device budget so this guard
     agrees with the planner's rule 1.  The branch is resolved on static
@@ -200,25 +198,27 @@ def _conv2d_xla_fallback(x, w, b, *, stride, padding, mode, relu=False):
     return jnp.maximum(out, 0) if relu else out
 
 
-def input_block_vmem_bytes(h_pad: int, w_pad: int, u: int,
-                           mode: ComputeMode) -> int:
-    return h_pad * w_pad * u * jnp.dtype(mode.operand_dtype).itemsize
-
-
 def fits_vmem(h: int, w: int, k: int, stride: int, padding: str, u: int,
               mode: ComputeMode, *, budget: Optional[int] = None) -> bool:
-    """True iff one (padded H x padded W x u) input block fits the budget.
+    """True iff one grid step of the kernel fits the scoped-VMEM budget.
 
-    ``budget`` defaults to the default device profile's VMEM block budget;
-    the planner passes its target profile's budget so rule 1 is evaluated
+    Counts what the step really holds (:func:`conv_vmem_bytes`: the
+    double-buffered input, weight and output blocks, the accumulator and
+    the per-tap temporaries) for a u-wide channel group in ``mode``'s
+    dtypes — an upper bound for the int8 datapath's 1-byte operands.
+    ``budget`` defaults to the default device profile's VMEM budget; the
+    planner passes its target profile's budget so rule 1 is evaluated
     against the device being planned *for*, not the module default.
     """
     if budget is None:
         budget = VMEM_INPUT_BUDGET
-    _, p0, p1 = _pad_amounts(h, k, stride, padding)
-    _, q0, q1 = _pad_amounts(w, k, stride, padding)
-    return input_block_vmem_bytes(h + p0 + p1, w + q0 + q1, u, mode) \
-        <= budget
+    h_out, _, _ = _pad_amounts(h, k, stride, padding)
+    w_out, _, _ = _pad_amounts(w, k, stride, padding)
+    return conv_vmem_bytes(h_out=h_out, w_out=w_out, kh=k, kw=k,
+                           stride=stride, u=u, u_out=u,
+                           operand_dtype=mode.operand_dtype,
+                           acc_dtype=mode.accum_dtype,
+                           out_dtype=mode.out_dtype) <= budget
 
 
 def _int8_dispatchable(plan, w) -> bool:
@@ -245,13 +245,11 @@ def _conv_pallas_planned(layer, plan, params, x):
         return conv2d_mapmajor_int8(x, params["w"], plan.qparams, b,
                                     stride=layer.stride,
                                     padding=layer.padding, u=plan.u,
-                                    interpret=jax.default_backend() != "tpu",
                                     vmem_budget=plan.vmem_budget)
     w = resolve_weight(params["w"], plan.mode)
     return conv2d_mapmajor(x, w, b,
                            stride=layer.stride, padding=layer.padding,
                            mode=plan.mode, u=plan.u,
-                           interpret=jax.default_backend() != "tpu",
                            vmem_budget=plan.vmem_budget)
 
 
@@ -271,13 +269,11 @@ def _conv_pallas_fused(layer, plan, params, x, epilogue):
         return conv2d_mapmajor_int8(x, params["w"], plan.qparams, b,
                                     stride=layer.stride,
                                     padding=layer.padding, u=plan.u,
-                                    interpret=jax.default_backend() != "tpu",
                                     vmem_budget=plan.vmem_budget,
                                     fuse_bias_relu=True)
     w = resolve_weight(params["w"], plan.mode)
     return conv2d_mapmajor(x, w, b,
                            stride=layer.stride, padding=layer.padding,
                            mode=plan.mode, u=plan.u,
-                           interpret=jax.default_backend() != "tpu",
                            vmem_budget=plan.vmem_budget,
                            fuse_bias_relu=True)

@@ -20,12 +20,14 @@ matmul schedule.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
 from ...core.precision import ComputeMode
 
 
@@ -67,7 +69,7 @@ def _mm_kernel(a_ref, b_ref, *refs, n_k: int, out_dtype, acc_dtype,
 def matmul_mapmajor(a: jnp.ndarray, b: jnp.ndarray, *,
                     mode: ComputeMode = ComputeMode.RELAXED,
                     bm: int = 256, bn: int = 256, bk: int = 512,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """(M, K) @ (K, N) under a compute mode.  Dims must divide the blocks
     (the ops.py wrapper pads)."""
     m, k = a.shape
@@ -89,7 +91,7 @@ def matmul_mapmajor(a: jnp.ndarray, b: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), mode.out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), mode.accum_dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a.astype(mode.operand_dtype), b.astype(mode.operand_dtype))
 
 
@@ -98,7 +100,7 @@ def matmul_mapmajor_int8(a: jnp.ndarray, b: jnp.ndarray, s: jnp.ndarray,
                          apply_relu: bool = False,
                          out_dtype=jnp.bfloat16,
                          bm: int = 256, bn: int = 256, bk: int = 512,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """The true int8 datapath for dense layers: int8 x int8 -> int32 MACs
     with the dequant(+bias+ReLU) epilogue fused into the flush.
 
@@ -140,5 +142,5 @@ def matmul_mapmajor_int8(a: jnp.ndarray, b: jnp.ndarray, s: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)

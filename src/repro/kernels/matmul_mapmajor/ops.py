@@ -9,6 +9,7 @@ dequant folded into the flush epilogue).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +43,7 @@ def _matmul_padded(a, b, mode, bm, bn, bk, interpret):
 
 def matmul(a, w, *, mode: ComputeMode = ComputeMode.RELAXED,
            bm: int = 256, bn: int = 256, bk: int = 512,
-           interpret: bool = True):
+           interpret: Optional[bool] = None):
     """(..., K) @ (K, N) with per-mode arithmetic; int8 weights dequantized
     at synthesis-prepared scale (the IMPRECISE_INT8 fallback when no
     activation qparams are available — see :func:`matmul_int8`)."""
@@ -75,7 +76,7 @@ def _matmul_padded_int8(a, wq, wscale, act_scale, b, bm, bn, bk, interpret,
 
 def matmul_int8(a, w: QuantizedTensor, qp: QParams, b=None, *,
                 relu: bool = False, bm: int = 256, bn: int = 256,
-                bk: int = 512, interpret: bool = True):
+                bk: int = 512, interpret: Optional[bool] = None):
     """(..., K) @ int8 (K, N) on the true int8 datapath: activations
     quantized to the calibrated static scale, int8 x int8 -> int32 MACs,
     fused dequant(+bias+ReLU) at flush — one launch for the whole group.
@@ -127,9 +128,8 @@ def _dense_pallas_planned(layer, plan, params, x):
     if _int8_dispatchable(plan, params["w"]):
         return matmul_int8(x2, params["w"], plan.qparams,
                            params.get("b") if layer.use_bias else None,
-                           bk=bk, interpret=jax.default_backend() != "tpu")
-    y = matmul(x2, params["w"], mode=plan.mode, bk=bk,
-               interpret=jax.default_backend() != "tpu")
+                           bk=bk)
+    y = matmul(x2, params["w"], mode=plan.mode, bk=bk)
     return add_bias(y, layer, params)
 
 
@@ -147,8 +147,7 @@ def _dense_pallas_fused(layer, plan, params, x, epilogue):
     b = params.get("b") if layer.use_bias else None
     if _int8_dispatchable(plan, params["w"]):
         return matmul_int8(x2, params["w"], plan.qparams, b, relu=True,
-                           bk=bk, interpret=jax.default_backend() != "tpu")
-    y = add_bias(matmul(x2, params["w"], mode=plan.mode, bk=bk,
-                        interpret=jax.default_backend() != "tpu"),
+                           bk=bk)
+    y = add_bias(matmul(x2, params["w"], mode=plan.mode, bk=bk),
                  layer, params)
     return jnp.maximum(y, 0)

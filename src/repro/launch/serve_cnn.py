@@ -26,17 +26,39 @@ table alongside the cache series.
 from __future__ import annotations
 
 import argparse
+from typing import Optional, Tuple
 
 import jax
 
 from repro.cnn import WORKLOADS, init_network_params
-from repro.core import ComputeMode, synthesize
+from repro.core import ComputeMode, NetworkDescription, synthesize
+from repro.core.synthesizer import SynthesizedProgram
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import (MetricsRegistry, Tracer, render_table,
                        write_metrics_json, write_trace_jsonl)
-from repro.serving import DISPATCH_POLICIES, ServingConfig, run_offered_load
+from repro.serving import (DISPATCH_POLICIES, LoadReport, ServingConfig,
+                           run_offered_load)
+
+
+def serve(net: NetworkDescription, params, *, mode: ComputeMode,
+          config: ServingConfig, requests: int, rate: float = 0.0,
+          seed: int = 0, registry: Optional[MetricsRegistry] = None,
+          tracer: Optional[Tracer] = None, store=None
+          ) -> Tuple[SynthesizedProgram, LoadReport]:
+    """Synthesize ``net`` once with every layer pinned to ``mode``, then
+    drive the replica tier with ``requests`` single images at ``rate``
+    req/s (0 = back-to-back).  ``store`` is an optional
+    :class:`~repro.artifacts.ArtifactStore` to hydrate the program from."""
+    program = synthesize(net, params, forced_mode=mode, registry=registry,
+                         tracer=tracer, artifact_store=store)
+    report = run_offered_load(program, requests=requests, rate=rate,
+                              config=config, seed=seed, registry=registry,
+                              tracer=tracer)
+    return program, report
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--net", default="squeezenet", choices=sorted(WORKLOADS))
     ap.add_argument("--scale", type=float, default=0.08)
@@ -76,9 +98,16 @@ def main():
         from repro.artifacts import ArtifactStore
         store = ArtifactStore(args.artifact_dir, registry=registry,
                               tracer=tracer)
-    program = synthesize(net, params, forced_mode=ComputeMode(args.mode),
-                         registry=registry, tracer=tracer,
-                         artifact_store=store)
+    config = ServingConfig(max_batch=args.max_batch,
+                           max_delay_s=args.max_delay_ms / 1e3,
+                           replicas=args.replicas,
+                           dispatch=args.dispatch,
+                           max_queue_depth=args.max_queue_depth,
+                           artifact_dir=args.artifact_dir)
+    program, report = serve(net, params, mode=ComputeMode(args.mode),
+                            config=config, requests=args.requests,
+                            rate=args.rate, seed=args.seed,
+                            registry=registry, tracer=tracer, store=store)
     if store is not None and store.hits:
         print(f"  program hydrated from {args.artifact_dir} "
               "(zero synthesis iterations), "
@@ -86,16 +115,6 @@ def main():
     else:
         print(f"  stages A-C in {program.synthesis_seconds:.2f}s, "
               f"program {program.fingerprint()}")
-
-    config = ServingConfig(max_batch=args.max_batch,
-                           max_delay_s=args.max_delay_ms / 1e3,
-                           replicas=args.replicas,
-                           dispatch=args.dispatch,
-                           max_queue_depth=args.max_queue_depth,
-                           artifact_dir=args.artifact_dir)
-    report = run_offered_load(program, requests=args.requests,
-                              rate=args.rate, config=config, seed=args.seed,
-                              registry=registry, tracer=tracer)
 
     srv, tier = report.server_stats, report.tier_stats
     print(f"served {report.admitted}/{report.requests} requests "

@@ -44,18 +44,18 @@ def percentile(sorted_vals: List[float], q: float) -> float:
 
 
 def warm_buckets(cache: ProgramCache, program: SynthesizedProgram,
-                 max_batch: int) -> float:
+                 max_batch: int, device=None) -> float:
     """Compile Stage D for every bucket the batcher can release (1, 2, ...,
-    max_batch) and run each compiled executable once on zeros, so neither
-    an XLA compile nor a first-execution cost (allocator growth, transfer
-    warmup) lands inside a measured window.  Returns the wall time spent
-    warming."""
+    max_batch) on ``device`` and run each compiled executable once on
+    zeros, so neither an XLA compile nor a first-execution cost (allocator
+    growth, transfer warmup) lands inside a measured window.  Returns the
+    wall time spent warming."""
     t0 = time.perf_counter()
     b = 1
     while b <= max_batch:
-        fn = cache.get_or_build(program, b)
+        fn = cache.get_or_build(program, b, device)
         x = np.zeros((b, *program.net.input_shape), np.float32)
-        jax.block_until_ready(fn(x))
+        jax.block_until_ready(fn(jax.device_put(x, device)))
         b *= 2
     return time.perf_counter() - t0
 
@@ -64,16 +64,17 @@ def warm_replicas(replica_set: ReplicaSet) -> List[float]:
     """Warm every replica's buckets; returns per-replica warm seconds.
 
     Cold start is per replica: each replica's program warms against the
-    *shared* cache, so identical replicas show the cache working (replica
-    0 pays the compiles, later replicas land hits and warm in ~0s) while
-    device-distinct replicas each pay their own Stage-D compiles — their
-    fingerprints can never alias.  The measured cost is recorded on
+    *shared* cache on its own device, so identical replicas on one device
+    show the cache working (the first pays the compiles, later ones land
+    hits and warm in ~0s) while replicas on distinct devices, or with
+    device-distinct profiles, each pay their own Stage-D compiles.  The
+    measured cost is recorded on
     ``Replica.warm_seconds`` and surfaces in ``BENCH_serving.json``.
     """
     seconds = []
     for r in replica_set.replicas:
         r.warm_seconds = warm_buckets(replica_set.cache, r.program,
-                                      replica_set.config.max_batch)
+                                      replica_set.config.max_batch, r.device)
         seconds.append(r.warm_seconds)
     return seconds
 
@@ -95,6 +96,9 @@ class LoadReport:
     warm_seconds: List[float] = field(default_factory=list)  # per replica
     registry: Optional[MetricsRegistry] = None   # the tier's metrics sink
     tracer: Optional[Tracer] = None              # the tier's span sink
+    tier: Optional[ReplicaSet] = None            # the tier that served
+    images: Optional[np.ndarray] = None          # admitted inputs, in order
+    outputs: Optional[np.ndarray] = None         # their served results
 
     @property
     def sustained_per_s(self) -> float:
@@ -181,16 +185,17 @@ def run_offered_load(program: Union[SynthesizedProgram, ReplicaSet], *,
         gap = 1.0 / rate if rate > 0 else 0.0
         t0 = time.perf_counter()
         futures = []
+        admitted = []
         shed = 0
         for i in range(requests):
             try:
                 futures.append(tier.submit(images[i]))
+                admitted.append(i)
             except LoadShedError:
                 shed += 1          # open loop: the arrival is dropped
             if gap:
                 time.sleep(max(0.0, t0 + (i + 1) * gap - time.perf_counter()))
-        for f in futures:
-            f.result(timeout=timeout_s)
+        outputs = [f.result(timeout=timeout_s) for f in futures]
         wall = time.perf_counter() - t0
 
     tier_stats = tier.stats()
@@ -207,4 +212,7 @@ def run_offered_load(program: Union[SynthesizedProgram, ReplicaSet], *,
         tier_stats=tier_stats,
         warm_seconds=warm_seconds,
         registry=tier.registry,
-        tracer=tier.tracer)
+        tracer=tier.tracer,
+        tier=tier,
+        images=images[admitted],
+        outputs=np.stack(outputs) if outputs else None)

@@ -7,9 +7,10 @@ split (DESIGN.md §6):
            :class:`SynthesizedProgram` — Stages A–C.  Admitted once per
            network (synthesis is seconds of work: planning, mode search
            over the validation set, weight preparation).
-  level 2  ``(network, batch bucket, program fingerprint)`` ->
+  level 2  ``(network, batch bucket, program fingerprint, device)`` ->
            :class:`BatchProgram` — Stage D, an AOT XLA compile for one
-           fixed batch shape.  Power-of-two buckets keep this level's
+           fixed batch shape on one device (the replica's chip).
+           Power-of-two buckets keep this level's
            cardinality at ``log2(max_batch) + 1`` per program.
   level 3  *(optional, persistent)* an :class:`~repro.artifacts.
            ArtifactStore`: before compiling, a level-2 miss first tries to
@@ -57,7 +58,8 @@ from ..obs import MetricsRegistry, Tracer
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from .config import ServingConfig
 
-CacheKey = Tuple[str, int, str]          # (network, bucket, program fp)
+#: (network, bucket, program fp, device id or None for the default device)
+CacheKey = Tuple[str, int, str, Optional[int]]
 
 
 class CacheStats:
@@ -214,9 +216,10 @@ class ProgramCache:
             return len(self._programs)
 
     # -- level 2: Stage-D executables ---------------------------------------
-    def get_or_build(self, program: SynthesizedProgram,
-                     batch: int) -> BatchProgram:
-        """The compiled executable for ``batch``, compiling on first use.
+    def get_or_build(self, program: SynthesizedProgram, batch: int,
+                     device=None) -> BatchProgram:
+        """The compiled executable for ``batch`` on ``device`` (a JAX
+        device; None = the default device), compiling on first use.
 
         ``program`` must have been :meth:`admit`-ted (enforced so the
         serving layer cannot leak unkeyed programs into the cache).
@@ -232,7 +235,8 @@ class ProgramCache:
         tests/test_program_cache_concurrency.py).
         """
         fp = program.fingerprint()
-        key: CacheKey = (program.net.name, batch, fp)
+        key: CacheKey = (program.net.name, batch, fp,
+                         None if device is None else device.id)
         with self._lock:
             if (program.net.name, fp) not in self._programs:
                 raise KeyError(
@@ -267,12 +271,12 @@ class ProgramCache:
                     with self.tracer.span(
                             "synthesis.stage_d_compile",
                             net=program.net.name, batch=batch) as s:
-                        compiled = program.for_batch(batch)
+                        compiled = program.for_batch(batch, device=device)
                         if s is not None:
                             s.attrs["compile_seconds"] = \
                                 compiled.compile_seconds
                 else:
-                    compiled = program.for_batch(batch)
+                    compiled = program.for_batch(batch, device=device)
                 self.stats.compiled(compiled.compile_seconds)
                 if self.store is not None:
                     try:          # write-back is best-effort persistence

@@ -22,10 +22,15 @@ batcher queue — behind one ``submit()`` front door:
               light-load coalescing is untouched, overload imbalance is
               flattened.
 
-Replicas share one :class:`~repro.serving.program_cache.ProgramCache`:
-identical replicas share Stage-D executables, while device-distinct
-replicas can never alias — the plan fingerprint covers the device profile
-identity (PR 4), so each device's compiles get their own entries.
+Replica *i* is bound to ``jax.devices()[i]`` (wrapping around when there
+are more replicas than devices): its Stage-D executables are compiled for
+that device, carry their weights there, and every bucket it serves is
+placed there — so a four-replica tier on a 2x2 host uses all four chips.
+Replicas share one :class:`~repro.serving.program_cache.ProgramCache`,
+keyed by device: identical replicas on one device share Stage-D
+executables, while replicas on different devices, or synthesized for
+different device profiles (the plan fingerprint covers the profile
+identity), never alias.
 
 Like the single server, the tier is dual-mode: ``start()``/``stop()`` run
 one dispatch thread per replica; ``pump()``/``drain()`` are hand-pumped
@@ -34,8 +39,9 @@ and deterministic for tests.
 from __future__ import annotations
 
 import threading
-import time
 from typing import Dict, List, Optional, Sequence, Union
+
+import jax
 
 from ..core.synthesizer import SynthesizedProgram
 from ..obs import MetricsRegistry, Tracer
@@ -47,7 +53,8 @@ from .server import SynthesisServer
 
 
 class Replica:
-    """One data-parallel replica: a synthesized program + its server.
+    """One data-parallel replica: a synthesized program + its server, bound
+    to one JAX device.
 
     ``warm_seconds`` is the replica's measured cold-start cost (Stage-D
     compiles for every bucket), recorded by
@@ -55,20 +62,23 @@ class Replica:
     """
 
     def __init__(self, index: int, program: SynthesizedProgram,
-                 config: ServingConfig, cache: ProgramCache, *,
+                 config: ServingConfig, cache: ProgramCache, device, *,
                  registry: Optional[MetricsRegistry] = None,
                  tracer: Optional[Tracer] = None):
         self.index = index
         self.program = program
+        self.device = device
         self.server = SynthesisServer(program, config=config, cache=cache,
                                       registry=registry, tracer=tracer,
-                                      labels={"replica": index})
+                                      labels={"replica": index},
+                                      device=device)
         self.stolen_requests = 0        # requests this replica stole
         self.peak_depth = 0             # max queue depth ever admitted to
         self.warm_seconds: Optional[float] = None
 
     @property
-    def device(self) -> str:
+    def profile(self) -> str:
+        """Name of the device profile the replica's plan was drawn for."""
         return self.program.plan.profile.name
 
     @property
@@ -76,8 +86,8 @@ class Replica:
         return self.server.batcher.depth
 
     def __repr__(self) -> str:
-        return (f"Replica({self.index}, device={self.device!r}, "
-                f"depth={self.depth})")
+        return (f"Replica({self.index}, device={self.device}, "
+                f"profile={self.profile!r}, depth={self.depth})")
 
 
 class ReplicaSet:
@@ -147,8 +157,9 @@ class ReplicaSet:
         self.registry = registry if registry is not None else \
             self.cache.registry
         self.tracer = tracer if tracer is not None else self.cache.tracer
+        devices = jax.devices()
         self.replicas: List[Replica] = [
-            Replica(i, p, config, self.cache,
+            Replica(i, p, config, self.cache, devices[i % len(devices)],
                     registry=self.registry, tracer=self.tracer)
             for i, p in enumerate(programs)]
         self._submitted = self.registry.counter(
@@ -306,13 +317,7 @@ class ReplicaSet:
             if bucket is not None:
                 srv.dispatch_bucket(bucket)
                 continue
-            with srv.batcher.not_empty:
-                if srv.batcher.depth == 0 and not self._stopping.is_set():
-                    srv.batcher.not_empty.wait(timeout=poll)
-            deadline = srv.batcher.next_deadline()
-            if deadline is not None:
-                self._stopping.wait(
-                    max(0.0, min(deadline - time.perf_counter(), poll)))
+            srv.wait_for_trigger(self._stopping, poll)
 
     def start(self) -> "ReplicaSet":
         if self._threads:
@@ -350,7 +355,8 @@ class ReplicaSet:
         """Tier-level accounting: admission, shedding, per-replica detail."""
         per_replica = []
         for r in self.replicas:
-            d = {"replica": r.index, "device": r.device,
+            d = {"replica": r.index, "device": str(r.device),
+                 "profile": r.profile,
                  "stolen_requests": r.stolen_requests,
                  "peak_depth": r.peak_depth,
                  **r.server.stats.as_dict()}

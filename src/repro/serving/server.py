@@ -28,7 +28,6 @@ from dataclasses import dataclass, field
 from typing import Dict, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..core.synthesizer import SynthesizedProgram
@@ -46,6 +45,8 @@ class ServerStats:
     batches: int = 0
     padded_slots: int = 0
     bucket_counts: Dict[int, int] = field(default_factory=dict)
+    #: Completed requests per device their output was computed on.
+    output_devices: Dict[str, int] = field(default_factory=dict)
 
     @property
     def dispatched_slots(self) -> int:
@@ -62,7 +63,8 @@ class ServerStats:
                 "padded_slots": self.padded_slots,
                 "padding_fraction": round(self.padding_fraction, 4),
                 "bucket_counts": {str(k): v for k, v
-                                  in sorted(self.bucket_counts.items())}}
+                                  in sorted(self.bucket_counts.items())},
+                "output_devices": dict(sorted(self.output_devices.items()))}
 
 
 class SynthesisServer:
@@ -75,6 +77,9 @@ class SynthesisServer:
     triggers Stage D, through the shared ``cache`` — pass one
     ``ProgramCache`` to several servers to share compiled buckets across
     replicas of the same network/plan (what ``ReplicaSet`` does).
+    ``device`` binds the server to one JAX device: its executables are
+    compiled for it and every bucket is placed on it (None = the default
+    device).
     """
 
     def __init__(self, program: SynthesizedProgram, *,
@@ -83,7 +88,8 @@ class SynthesisServer:
                  policy: Optional[FlushPolicy] = None,
                  registry: Optional[MetricsRegistry] = None,
                  tracer: Optional[Tracer] = None,
-                 labels: Optional[Dict[str, object]] = None):
+                 labels: Optional[Dict[str, object]] = None,
+                 device=None):
         if policy is not None:
             if config is not None:
                 raise ValueError("pass either config= or the deprecated "
@@ -95,6 +101,7 @@ class SynthesisServer:
             config = ServingConfig.from_flush_policy(policy)
         self.config = config or ServingConfig()
         self.program = program
+        self.device = device
         self.cache = cache if cache is not None else \
             ProgramCache(config=self.config, registry=registry, tracer=tracer)
         self.policy = self.config.flush_policy()
@@ -157,13 +164,17 @@ class SynthesisServer:
             if self.tracer is not None else None
         span = span_cm.__enter__() if span_cm is not None else None
         try:
-            compiled = self.cache.get_or_build(self.program, bucket.batch)
-            x = jnp.stack([jnp.asarray(r.image, self.program.input_dtype)
-                           for r in bucket.requests])
-            if bucket.padding:
-                pad = jnp.zeros((bucket.padding, *x.shape[1:]), x.dtype)
-                x = jnp.concatenate([x, pad])
-            out = np.asarray(jax.block_until_ready(compiled(x)))
+            compiled = self.cache.get_or_build(self.program, bucket.batch,
+                                               self.device)
+            # One host buffer per bucket (zero rows pad it), one transfer.
+            x = np.zeros((bucket.batch, *self.program.net.input_shape),
+                         self.program.input_dtype)
+            for i, r in enumerate(bucket.requests):
+                x[i] = r.image
+            y = jax.block_until_ready(
+                compiled(jax.device_put(x, self.device)))
+            where = ",".join(sorted(str(d) for d in y.devices()))
+            out = np.asarray(y)
             self._dispatch_seconds.observe(self.registry.clock() - t0,
                                            **self._labels)
             with self._stats_lock:
@@ -171,6 +182,9 @@ class SynthesisServer:
                 self.stats.padded_slots += bucket.padding
                 self.stats.bucket_counts[bucket.batch] = \
                     self.stats.bucket_counts.get(bucket.batch, 0) + 1
+                self.stats.output_devices[where] = \
+                    self.stats.output_devices.get(where, 0) \
+                    + len(bucket.requests)
             for i, req in enumerate(bucket.requests):
                 req.future.set_result(out[i])
                 with self._stats_lock:
@@ -204,22 +218,29 @@ class SynthesisServer:
             served += n
 
     # -- background loop ----------------------------------------------------
+    def wait_for_trigger(self, stopping: threading.Event,
+                         poll: float) -> None:
+        """Sleep until a flush trigger may have fired: a submit (which can
+        complete a full bucket), the oldest request's deadline, or at most
+        ``poll`` seconds.  The trigger is checked under the batcher's lock
+        that ``submit`` notifies under, so no wakeup is lost."""
+        batcher = self.batcher
+        with batcher.not_empty:
+            if stopping.is_set() or batcher.ready():
+                return
+            deadline = batcher.next_deadline()
+            timeout = poll if deadline is None else \
+                max(0.0, min(deadline - time.perf_counter(), poll))
+            batcher.not_empty.wait(timeout=timeout)
+
     def _loop(self) -> None:
         poll = max(self.policy.max_delay_s, 1e-4)
         while not self._stopping.is_set():
-            with self.batcher.not_empty:
-                if self.batcher.depth == 0 and not self._stopping.is_set():
-                    self.batcher.not_empty.wait(timeout=poll)
             bucket = self.batcher.take()
             if bucket is not None:
                 self.dispatch_bucket(bucket)
                 continue
-            # queued but no trigger fired yet: sleep until the oldest
-            # request's deadline (capped at poll so stop() stays responsive)
-            deadline = self.batcher.next_deadline()
-            if deadline is not None:
-                self._stopping.wait(
-                    max(0.0, min(deadline - time.perf_counter(), poll)))
+            self.wait_for_trigger(self._stopping, poll)
 
     def start(self) -> "SynthesisServer":
         if self._thread is not None:

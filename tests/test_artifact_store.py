@@ -29,7 +29,7 @@ import numpy as np
 import pytest
 
 from repro.artifacts import (ARTIFACT_SCHEMA_VERSION, ArtifactStore,
-                             executables_supported, synthesis_request_key)
+                             synthesis_request_key)
 from repro.cnn import init_network_params
 from repro.core import NetworkDescription, run_network, synthesize
 from repro.obs import MetricsRegistry, Tracer
@@ -279,8 +279,6 @@ def test_cache_l3_warm_start_zero_compiles(fresh_program, tmp_path):
     assert cold.cache.stats.stage_d_compiles == 3          # buckets 1, 2, 4
     assert cold.cache.store.writes >= 3
 
-    if not executables_supported():
-        pytest.skip("jax.export unavailable: plan-only fallback platform")
     warm_reg = MetricsRegistry()
     warm = ReplicaSet(fresh_program, config=cfg, registry=warm_reg)
     warm_replicas(warm)
@@ -341,8 +339,6 @@ def test_program_cache_store_kwarg_round_trip(fresh_program, tmp_path):
     built = c1.get_or_build(fresh_program, 2)
     assert built.compile_seconds > 0.0                     # genuinely compiled
 
-    if not executables_supported():
-        pytest.skip("jax.export unavailable on this platform")
     store2 = ArtifactStore(str(tmp_path))
     c2 = ProgramCache(store=store2)
     c2.admit(fresh_program)

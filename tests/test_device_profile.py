@@ -2,6 +2,7 @@
 profile cache, profile-aware planning, and device-keyed program identity."""
 import dataclasses
 import json
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -10,11 +11,12 @@ import pytest
 from repro.cnn import alexnet, init_network_params
 from repro.core import (ComputeMode, IMPL_PALLAS, IMPL_XLA, NetworkDescription,
                         PlannerConfig, plan_network, synthesize)
-from repro.device import (CPU_INTERPRET, PROFILE_SCHEMA_VERSION, TPU_V4,
-                          TPU_V5E, DeviceProfile, ProfileSchemaError,
+from repro.device import (CPU_INTERPRET, DEFAULT_PROFILE,
+                          PROFILE_SCHEMA_VERSION, TPU_PROFILES_BY_KIND,
+                          TPU_V4, TPU_V5E, DeviceProfile, ProfileSchemaError,
                           calibrate, get_profile, load_cached_profile,
-                          registered_profiles, resolve_profile,
-                          store_cached_profile)
+                          profile_for_device, registered_profiles,
+                          resolve_profile, store_cached_profile)
 from repro.serving import ProgramCache
 
 jax.config.update("jax_platform_name", "cpu")
@@ -174,9 +176,41 @@ def test_resolve_profile_passthrough_and_names():
     assert resolve_profile("tpu_v4") is TPU_V4
 
 
+# ------------------------------------------------ device kind -> profile ----
+def _device(platform, kind):
+    return SimpleNamespace(platform=platform, device_kind=kind)
+
+
+@pytest.mark.parametrize("kind,want", [("TPU v5 lite", TPU_V5E),
+                                       ("TPU v4", TPU_V4)])
+def test_profile_for_device_by_tpu_kind(kind, want):
+    assert TPU_PROFILES_BY_KIND[kind] is want
+    assert profile_for_device(_device("tpu", kind)) is want
+
+
+def test_profile_for_device_rejects_unknown_tpu_kind():
+    with pytest.raises(KeyError, match="TPU v7x"):
+        profile_for_device(_device("tpu", "TPU v7x"))
+
+
+def test_profile_for_device_off_tpu_is_the_default():
+    assert profile_for_device(_device("cpu", "cpu")) is DEFAULT_PROFILE
+    assert profile_for_device() is DEFAULT_PROFILE      # this CPU host
+
+
+def test_synthesize_without_a_target_plans_for_this_device():
+    net = NetworkDescription("tiny", (3, 8, 8))
+    net.conv("c", 4, 3, stride=1, padding="SAME", inputs=("input",))
+    prog = synthesize(net, init_network_params(net, jax.random.PRNGKey(0)),
+                      forced_mode=ComputeMode.RELAXED)
+    assert prog.plan.profile is profile_for_device()
+
+
 # ------------------------------------------------------ planner routing ----
 def _wide_conv_net():
-    net = NetworkDescription("wide", (128, 128, 128))
+    # 64x64 keeps one grid step of the kernel (double-buffered blocks,
+    # accumulator, temporaries) inside the v5e budget; 128x128 does not.
+    net = NetworkDescription("wide", (128, 64, 64))
     net.conv("cwide", 128, 3, stride=1, padding="SAME", inputs=("input",))
     return net
 

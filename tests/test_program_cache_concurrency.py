@@ -119,7 +119,7 @@ class _RendezvousProgram:
     def fingerprint(self) -> str:
         return "rendezvous-fp"
 
-    def for_batch(self, batch: int):
+    def for_batch(self, batch: int, device=None):
         self.barrier.wait(timeout=30.0)          # all builders inside at once
         with self._lock:
             self.concurrent_builds += 1

@@ -49,7 +49,7 @@ class _FakeBatch:
     def __call__(self, x):
         if self.delay_s:
             time.sleep(self.delay_s)
-        return np.asarray(x) * 2.0
+        return jnp.asarray(x) * 2.0
 
 
 class FakeProgram:
@@ -68,7 +68,7 @@ class FakeProgram:
     def fingerprint(self):
         return self._fp
 
-    def for_batch(self, batch):
+    def for_batch(self, batch, device=None):
         return _FakeBatch(self._delay_s)
 
 
@@ -359,7 +359,7 @@ def test_device_mesh_replicas_never_alias_in_the_shared_cache(small_net):
         net, params, ["tpu_v5e", "tpu_v4"],
         config=ServingConfig(max_batch=2, max_delay_s=60.0, replicas=2),
         forced_mode=ComputeMode.RELAXED)
-    assert [r.device for r in tier.replicas] == ["tpu_v5e", "tpu_v4"]
+    assert [r.profile for r in tier.replicas] == ["tpu_v5e", "tpu_v4"]
     fps = {r.program.fingerprint() for r in tier.replicas}
     assert len(fps) == 2                          # profiles keep them apart
 
@@ -373,3 +373,63 @@ def test_device_mesh_replicas_never_alias_in_the_shared_cache(small_net):
     outs = _serve_through(tier, imgs)
     assert outs.shape == (4, 10)
     assert np.isfinite(outs).all()
+
+
+# ----------------------------------------- one replica per device (child) ---
+_PLACEMENT_SCRIPT = r"""
+import json
+import jax
+import numpy as np
+from repro.cnn import init_network_params, squeezenet
+from repro.core import ComputeMode, synthesize
+from repro.serving import ReplicaSet, ServingConfig
+
+net = squeezenet(scale=0.08, num_classes=10, input_hw=64)
+program = synthesize(net, init_network_params(net, jax.random.PRNGKey(0)),
+                     forced_mode=ComputeMode.RELAXED)
+tier = ReplicaSet(program, config=ServingConfig(max_batch=2,
+                                                max_delay_s=60.0,
+                                                replicas=4))
+imgs = np.random.default_rng(0).standard_normal(
+    (8, *net.input_shape)).astype(np.float32)
+futs = [tier.submit(x) for x in imgs]
+tier.drain()
+four = np.stack([f.result(timeout=30.0) for f in futs])
+one = np.asarray(program.for_batch(8)(imgs))
+print("RESULT " + json.dumps({
+    "devices": [str(d) for d in jax.devices()],
+    "replica_devices": [str(r.device) for r in tier.replicas],
+    "output_devices": [r["output_devices"]
+                       for r in tier.stats()["replicas"]],
+    "compiles": tier.cache.stats.stage_d_compiles,
+    "equal": bool(np.array_equal(four, one)),
+}))
+"""
+
+
+def test_replica_i_runs_on_device_i():
+    """On a host with four devices, replica i compiles for, and serves
+    from, ``jax.devices()[i]`` — a CPU child with four host devices stands
+    in for a 2x2 TPU host."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    proc = subprocess.run([sys.executable, "-c", _PLACEMENT_SCRIPT],
+                          capture_output=True, text=True, timeout=300,
+                          env=env)
+    assert proc.returncode == 0, proc.stderr
+    line = [ln for ln in proc.stdout.splitlines() if ln.startswith("RESULT ")]
+    got = json.loads(line[-1][len("RESULT "):])
+    assert len(got["devices"]) == 4
+    assert got["replica_devices"] == got["devices"]
+    # least-loaded admission deals two requests to each replica: one
+    # full bucket each, computed on that replica's own device only
+    assert got["output_devices"] == [{d: 2} for d in got["devices"]]
+    assert got["compiles"] == 4           # one batch-2 executable per device
+    assert got["equal"]
