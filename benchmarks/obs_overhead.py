@@ -29,7 +29,7 @@ import numpy as np
 from repro.cnn import WORKLOADS, init_network_params
 from repro.core import ComputeMode, synthesize
 from repro.obs import (MetricsRegistry, Tracer, measure_drift, render_table,
-                       write_metrics_json, write_trace_jsonl)
+                       write_metrics_json)
 from repro.serving import ReplicaSet, ServingConfig
 from repro.serving.loadgen import warm_replicas
 from repro.launch.compile_cache import enable_compile_cache
@@ -169,7 +169,7 @@ def main():
                                  "overhead_pct": m["overhead_pct"]})
         print(f"\nmetrics snapshot -> {args.metrics_out}")
     if args.trace_out:
-        write_trace_jsonl(args.trace_out, obs["tracer"])
+        obs["tracer"].export_jsonl(args.trace_out)
         print(f"trace spans -> {args.trace_out}")
 
 

@@ -26,7 +26,7 @@ import jax
 from repro.cnn import WORKLOADS, init_network_params
 from repro.core import ComputeMode, synthesize
 from repro.obs import (MetricsRegistry, Tracer, measure_drift, render_table,
-                       write_metrics_json, write_trace_jsonl)
+                       write_metrics_json)
 from repro.serving import DISPATCH_POLICIES, ServingConfig, run_offered_load
 from repro.launch.compile_cache import enable_compile_cache
 
@@ -182,7 +182,7 @@ def main():
                                  "net": args.net, "replicas": args.replicas})
         print(f"\nmetrics snapshot -> {args.metrics_out}")
     if args.trace_out:
-        write_trace_jsonl(args.trace_out, obs["tracer"])
+        obs["tracer"].export_jsonl(args.trace_out)
         print(f"trace spans -> {args.trace_out}")
 
 

@@ -14,6 +14,13 @@ and a metrics snapshot rendered from the tier's registry
 (``repro.obs``).  ``--metrics-out``/``--trace-out`` export the snapshot
 (JSON) and the trace spans (JSONL) for offline analysis.
 
+``--profile-out DIR`` captures a JAX profiler trace of the serving run
+(``DIR/plugins/profile/<time>/*.xplane.pb``, for TensorBoard, Perfetto or
+``jax.profiler.ProfileData``) with the tracer's spans mirrored into it
+through ``jax.profiler.TraceAnnotation``: each ``serve.dispatch`` and its
+``serve.dispatch.<phase>`` children sit on the dispatch thread's line,
+on the same clock as the device's operations.
+
 ``--artifact-dir PATH`` attaches a persistent
 :class:`~repro.artifacts.ArtifactStore` (DESIGN.md §13): the first launch
 synthesizes and compiles cold while persisting every artifact; subsequent
@@ -26,6 +33,7 @@ table alongside the cache series.
 from __future__ import annotations
 
 import argparse
+import contextlib
 from typing import Optional, Tuple
 
 import jax
@@ -34,8 +42,7 @@ from repro.cnn import WORKLOADS, init_network_params
 from repro.core import ComputeMode, NetworkDescription, synthesize
 from repro.core.synthesizer import SynthesizedProgram
 from repro.launch.compile_cache import enable_compile_cache
-from repro.obs import (MetricsRegistry, Tracer, render_table,
-                       write_metrics_json, write_trace_jsonl)
+from repro.obs import MetricsRegistry, Tracer, render_table, write_metrics_json
 from repro.serving import (DISPATCH_POLICIES, LoadReport, ServingConfig,
                            run_offered_load)
 
@@ -43,17 +50,21 @@ from repro.serving import (DISPATCH_POLICIES, LoadReport, ServingConfig,
 def serve(net: NetworkDescription, params, *, mode: ComputeMode,
           config: ServingConfig, requests: int, rate: float = 0.0,
           seed: int = 0, registry: Optional[MetricsRegistry] = None,
-          tracer: Optional[Tracer] = None, store=None
+          tracer: Optional[Tracer] = None, store=None,
+          profile_dir: Optional[str] = None
           ) -> Tuple[SynthesizedProgram, LoadReport]:
     """Synthesize ``net`` once with every layer pinned to ``mode``, then
     drive the replica tier with ``requests`` single images at ``rate``
     req/s (0 = back-to-back).  ``store`` is an optional
-    :class:`~repro.artifacts.ArtifactStore` to hydrate the program from."""
+    :class:`~repro.artifacts.ArtifactStore` to hydrate the program from;
+    ``profile_dir`` captures a profiler trace of the serving run there."""
     program = synthesize(net, params, forced_mode=mode, registry=registry,
                          tracer=tracer, artifact_store=store)
-    report = run_offered_load(program, requests=requests, rate=rate,
-                              config=config, seed=seed, registry=registry,
-                              tracer=tracer)
+    with (jax.profiler.trace(profile_dir) if profile_dir
+          else contextlib.nullcontext()):
+        report = run_offered_load(program, requests=requests, rate=rate,
+                                  config=config, seed=seed,
+                                  registry=registry, tracer=tracer)
     return program, report
 
 
@@ -85,6 +96,9 @@ def main():
                     help="write a JSON metrics snapshot here")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write trace spans as JSONL here")
+    ap.add_argument("--profile-out", default=None, metavar="DIR",
+                    help="capture a profiler trace of the serving run "
+                         "here, with the trace spans mirrored into it")
     args = ap.parse_args()
 
     net = WORKLOADS[args.net](scale=args.scale, num_classes=args.classes,
@@ -92,7 +106,9 @@ def main():
     params = init_network_params(net, jax.random.PRNGKey(args.seed))
     print(f"synthesizing {net.name} ({len(net.layers)} layers)...")
     registry = MetricsRegistry()
-    tracer = Tracer(clock=registry.clock)
+    tracer = Tracer(clock=registry.clock,
+                    annotate=jax.profiler.TraceAnnotation
+                    if args.profile_out else None)
     store = None
     if args.artifact_dir:
         from repro.artifacts import ArtifactStore
@@ -107,7 +123,8 @@ def main():
     program, report = serve(net, params, mode=ComputeMode(args.mode),
                             config=config, requests=args.requests,
                             rate=args.rate, seed=args.seed,
-                            registry=registry, tracer=tracer, store=store)
+                            registry=registry, tracer=tracer, store=store,
+                            profile_dir=args.profile_out)
     if store is not None and store.hits:
         print(f"  program hydrated from {args.artifact_dir} "
               "(zero synthesis iterations), "
@@ -144,8 +161,10 @@ def main():
                                  "replicas": args.replicas})
         print(f"\nmetrics snapshot -> {args.metrics_out}")
     if args.trace_out:
-        write_trace_jsonl(args.trace_out, report.tracer or tracer)
+        (report.tracer or tracer).export_jsonl(args.trace_out)
         print(f"trace spans -> {args.trace_out}")
+    if args.profile_out:
+        print(f"profiler trace -> {args.profile_out}")
 
 
 if __name__ == "__main__":

@@ -17,7 +17,7 @@ Three dependency-free pieces plus one jax-coupled probe:
 from __future__ import annotations
 
 from .export import (parse_prometheus, render_table, snapshot_document,
-                     to_prometheus, write_metrics_json, write_trace_jsonl)
+                     to_prometheus, write_metrics_json)
 from .metrics import (FRACTION_BUCKETS, LATENCY_BUCKETS_S, Counter, Gauge,
                       Histogram, MetricsRegistry, pretouch)
 from .trace import Span, Tracer
@@ -27,7 +27,7 @@ __all__ = [
     "LATENCY_BUCKETS_S", "FRACTION_BUCKETS",
     "Span", "Tracer",
     "to_prometheus", "parse_prometheus", "render_table",
-    "snapshot_document", "write_metrics_json", "write_trace_jsonl",
+    "snapshot_document", "write_metrics_json",
     "GroupDrift", "DriftReport", "measure_drift",
 ]
 

@@ -24,7 +24,6 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
-from .trace import Tracer
 
 _ESCAPES = {"\\": "\\\\", "\n": "\\n", '"': '\\"'}
 
@@ -125,11 +124,6 @@ def write_metrics_json(path: str, registry: MetricsRegistry, *,
         json.dump(snapshot_document(registry, meta=meta), f, indent=2,
                   sort_keys=True)
         f.write("\n")
-
-
-def write_trace_jsonl(path: str, tracer: Tracer) -> int:
-    """Alias of :meth:`Tracer.export_jsonl` for symmetry at call sites."""
-    return tracer.export_jsonl(path)
 
 
 def render_table(registry: MetricsRegistry, *,

@@ -20,17 +20,23 @@ Tracing is opt-in: every instrumented call site takes ``tracer=None``
 and skips span bookkeeping entirely when no tracer is supplied, so the
 serving hot path pays nothing until someone asks for a trace.  The
 export format is JSONL — one span per line, ``parent_id`` linking the
-forest — consumed by ``serve_cnn --trace-out`` and the CI artifact
-upload.
+forest — written by ``serve_cnn --trace-out``.
+
+``Tracer(annotate=...)`` mirrors every span opened with
+:meth:`Tracer.span` into a second recorder: ``annotate(name)`` returns a
+context manager entered just before the span opens and exited just after
+it closes.  Passing ``jax.profiler.TraceAnnotation`` puts the spans in the
+profiler's own trace, on their thread's line and on the device trace's
+clock (``serve_cnn --profile-out``).  This module imports no jax; the
+caller supplies the factory.
 """
 from __future__ import annotations
 
 import json
 import threading
 import time
-from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, ContextManager, Dict, List, Optional
 
 #: Attribute values are kept JSON-scalar so export never fails mid-run.
 _SCALARS = (str, int, float, bool, type(None))
@@ -74,12 +80,19 @@ class Tracer:
 
     ``enabled=False`` turns every entry point into a no-op (the spans
     list stays empty) — the other half of the obs_overhead A/B.
+
+    ``annotate`` (default None: off) maps a span name to a context
+    manager that encloses each :meth:`span` (see the module docstring).
+    Retroactive spans — :meth:`record_span` and :meth:`event` — are not
+    mirrored: their start has passed by the time they are recorded.
     """
 
     def __init__(self, *, clock: Callable[[], float] = time.perf_counter,
-                 enabled: bool = True):
+                 enabled: bool = True,
+                 annotate: Optional[Callable[[str], ContextManager]] = None):
         self.clock = clock
         self.enabled = enabled
+        self.annotate = annotate
         self._lock = threading.Lock()
         self._spans: List[Span] = []
         self._next_id = 0
@@ -109,28 +122,14 @@ class Tracer:
             self._spans.append(span)
 
     # -- recording -----------------------------------------------------------
-    @contextmanager
-    def span(self, name: str, **attrs):
+    def span(self, name: str, **attrs) -> "_SpanScope":
         """Open a nested span around the with-block.
 
         Yields the :class:`Span` so the block can attach late attributes
         (``span.attrs["batch"] = n``).  Closes — and records — the span
         even when the block raises, tagging it ``error=True``.
         """
-        if not self.enabled:
-            yield None
-            return
-        s = self._new_span(name, self.clock(), attrs)
-        stack = self._stack()
-        stack.append(s)
-        try:
-            yield s
-        except BaseException:
-            s.attrs["error"] = True
-            raise
-        finally:
-            stack.pop()
-            self._finish(s, self.clock())
+        return _SpanScope(self, name, attrs)
 
     def event(self, name: str, **attrs) -> Optional[Span]:
         """A zero-duration span at "now" (shed/steal/demotion markers)."""
@@ -167,10 +166,6 @@ class Tracer:
     def by_name(self, name: str) -> List[Span]:
         return [s for s in self.finished() if s.name == name]
 
-    def to_jsonl(self) -> str:
-        return "".join(json.dumps(s.as_dict(), sort_keys=True) + "\n"
-                       for s in self.finished())
-
     def export_jsonl(self, path: str) -> int:
         """Write one JSON object per completed span; returns span count."""
         spans = self.finished()
@@ -178,3 +173,46 @@ class Tracer:
             for s in spans:
                 f.write(json.dumps(s.as_dict(), sort_keys=True) + "\n")
         return len(spans)
+
+
+class _SpanScope:
+    """The context manager :meth:`Tracer.span` returns (a class, not a
+    generator: it runs on the serving hot path when tracing is on)."""
+
+    __slots__ = ("_tracer", "_name", "_attrs", "_span", "_mirror")
+
+    def __init__(self, tracer: Tracer, name: str, attrs: Dict[str, object]):
+        self._tracer = tracer
+        self._name = name
+        self._attrs = attrs
+        self._span: Optional[Span] = None
+        self._mirror: Optional[ContextManager] = None
+
+    def __enter__(self) -> Optional[Span]:
+        tr = self._tracer
+        if not tr.enabled:
+            return None
+        if tr.annotate is not None:
+            self._mirror = tr.annotate(self._name)
+            self._mirror.__enter__()
+        s = self._span = tr._new_span(self._name, 0.0, self._attrs)
+        tr._stack().append(s)
+        s.t_start = tr.clock()
+        return s
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        s = self._span
+        if s is None:
+            return False
+        tr = self._tracer
+        tr._stack().pop()
+        if exc_type is not None:
+            s.attrs["error"] = True
+        # The clock is read as late as possible on entry and as early as
+        # possible on exit, so the span holds almost none of its own
+        # bookkeeping, and the mirror encloses it closely.
+        t_end = tr.clock()
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
+        tr._finish(s, t_end)
+        return False

@@ -118,6 +118,7 @@ class Bucket:
     """One released batch: the requests plus the pow-2 shape to pad to."""
     requests: List[Request]
     batch: int                       # pow2_bucket(len(requests))
+    released: float                  # perf_counter time it left the queue
 
     @property
     def padding(self) -> int:
@@ -246,7 +247,7 @@ class DynamicBatcher:
             n = min(len(self._queue), self.policy.max_batch)
             reqs, self._queue = self._queue[:n], self._queue[n:]
             self._observe_depth_locked()
-            bucket = Bucket(requests=reqs, batch=pow2_bucket(n))
+            bucket = Bucket(requests=reqs, batch=pow2_bucket(n), released=t)
         self._flushes.inc(reason=reason, **self._labels)
         self._occupancy.observe(len(reqs) / bucket.batch, **self._labels)
         if self.tracer is not None:

@@ -39,6 +39,7 @@ and deterministic for tests.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Union
 
 import jax
@@ -275,7 +276,8 @@ class ReplicaSet:
         if self.tracer is not None:
             self.tracer.event("serve.steal", thief=thief, victim=victim,
                               requests=len(stolen))
-        return Bucket(requests=stolen, batch=pow2_bucket(len(stolen)))
+        return Bucket(requests=stolen, batch=pow2_bucket(len(stolen)),
+                      released=time.perf_counter())
 
     def _take_for(self, i: int, force: bool = False) -> Optional[Bucket]:
         """One replica's next bucket: its own queue first, then a steal."""

@@ -24,6 +24,7 @@ from __future__ import annotations
 import threading
 import time
 import warnings
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Dict, Optional
 
@@ -35,6 +36,50 @@ from ..obs import MetricsRegistry, Tracer
 from .batcher import Bucket, DynamicBatcher, FlushPolicy, ServingFuture
 from .config import ServingConfig
 from .program_cache import ProgramCache
+
+#: A phase (or the dispatch span) with no tracer attached: one reusable
+#: no-op context.
+_UNTRACED = nullcontext()
+
+
+def _no_phase(name: str) -> nullcontext:
+    return _UNTRACED
+
+
+class _Phases:
+    """The phase spans of one traced dispatch, back to back.
+
+    Each phase starts where the one before it ended (the first where the
+    dispatch began), so what runs between two phases, tracing's own
+    bookkeeping included, is charged to the later one, and the phases
+    cover their parent.  Each carries ``cpu_s``, the dispatch thread's
+    CPU seconds over the same stretch; the rest of its wall time went to
+    waiting: for the GIL, a lock, the device or the scheduler.
+    """
+
+    __slots__ = ("_tracer", "_labels", "_t", "_cpu", "_scope", "_span")
+
+    def __init__(self, tracer: Tracer, parent, labels: Dict[str, str]):
+        self._tracer = tracer
+        self._labels = labels
+        self._t = parent.t_start
+        self._cpu = time.thread_time()
+
+    def __call__(self, name: str) -> "_Phases":
+        self._scope = self._tracer.span(name, **self._labels)
+        return self
+
+    def __enter__(self) -> None:
+        self._span = self._scope.__enter__()
+        self._span.t_start = self._t
+
+    def __exit__(self, *exc) -> bool:
+        cpu = time.thread_time()
+        self._span.attrs["cpu_s"] = cpu - self._cpu
+        self._cpu = cpu
+        done = self._scope.__exit__(*exc)
+        self._t = self._span.t_end
+        return done
 
 
 @dataclass
@@ -156,49 +201,72 @@ class SynthesisServer:
         Public because the replica tier dispatches buckets it took (or
         stole) itself; the bucket need not come from this server's own
         batcher — work stealing dispatches a peer's requests here.
+
+        Traced, the ``serve.dispatch`` span holds six back-to-back child
+        spans ``serve.dispatch.<phase>``, each with ``cpu_s``: ``lookup``
+        (the cache, fingerprint included), ``assemble`` (the host
+        buffer), ``transfer`` (``device_put`` as the host sees it),
+        ``execute`` (the call to ``block_until_ready``, with whatever of
+        the copy to the device is still in flight), ``copy_out``
+        (``devices()`` and ``np.asarray``) and ``complete`` (histogram,
+        stats, futures).  Then each request gets a ``serve.request``
+        span, enqueue to result, with ``queue_s`` (enqueue to release)
+        and ``dispatch`` (the ``span_id`` of the span that served it).
         """
         t0 = self.registry.clock()
-        span_cm = self.tracer.span("serve.dispatch", batch=bucket.batch,
-                                   requests=len(bucket.requests),
-                                   **self._labels) \
-            if self.tracer is not None else None
-        span = span_cm.__enter__() if span_cm is not None else None
-        try:
-            compiled = self.cache.get_or_build(self.program, bucket.batch,
-                                               self.device)
-            # One host buffer per bucket (zero rows pad it), one transfer.
-            x = np.zeros((bucket.batch, *self.program.net.input_shape),
-                         self.program.input_dtype)
-            for i, r in enumerate(bucket.requests):
-                x[i] = r.image
-            y = jax.block_until_ready(
-                compiled(jax.device_put(x, self.device)))
-            where = ",".join(sorted(str(d) for d in y.devices()))
-            out = np.asarray(y)
-            self._dispatch_seconds.observe(self.registry.clock() - t0,
-                                           **self._labels)
-            with self._stats_lock:
-                self.stats.batches += 1
-                self.stats.padded_slots += bucket.padding
-                self.stats.bucket_counts[bucket.batch] = \
-                    self.stats.bucket_counts.get(bucket.batch, 0) + 1
-                self.stats.output_devices[where] = \
-                    self.stats.output_devices.get(where, 0) \
-                    + len(bucket.requests)
-            for i, req in enumerate(bucket.requests):
-                req.future.set_result(out[i])
-                with self._stats_lock:
-                    self.stats.completed += 1
-        except Exception as exc:  # surface the failure on every request
-            if span is not None:
-                span.attrs["error"] = True
+        tracer = self.tracer
+        with (_UNTRACED if tracer is None else
+              tracer.span("serve.dispatch", batch=bucket.batch,
+                          requests=len(bucket.requests),
+                          **self._labels)) as span:
+            phase = _no_phase if span is None else \
+                _Phases(tracer, span, self._labels)
+            try:
+                with phase("serve.dispatch.lookup"):
+                    compiled = self.cache.get_or_build(
+                        self.program, bucket.batch, self.device)
+                with phase("serve.dispatch.assemble"):
+                    # One host buffer per bucket (zero rows pad it).
+                    x = np.zeros((bucket.batch, *self.program.net.input_shape),
+                                 self.program.input_dtype)
+                    for i, r in enumerate(bucket.requests):
+                        x[i] = r.image
+                with phase("serve.dispatch.transfer"):
+                    x_dev = jax.device_put(x, self.device)
+                with phase("serve.dispatch.execute"):
+                    y = jax.block_until_ready(compiled(x_dev))
+                with phase("serve.dispatch.copy_out"):
+                    where = ",".join(sorted(str(d) for d in y.devices()))
+                    out = np.asarray(y)
+                with phase("serve.dispatch.complete"):
+                    self._dispatch_seconds.observe(
+                        self.registry.clock() - t0, **self._labels)
+                    with self._stats_lock:
+                        self.stats.batches += 1
+                        self.stats.padded_slots += bucket.padding
+                        self.stats.bucket_counts[bucket.batch] = \
+                            self.stats.bucket_counts.get(bucket.batch, 0) + 1
+                        self.stats.output_devices[where] = \
+                            self.stats.output_devices.get(where, 0) \
+                            + len(bucket.requests)
+                    for i, req in enumerate(bucket.requests):
+                        req.future.set_result(out[i])
+                        with self._stats_lock:
+                            self.stats.completed += 1
+            except Exception as exc:  # surface the failure on every request
+                if span is not None:
+                    span.attrs["error"] = True
+                for req in bucket.requests:
+                    req.future.set_exception(exc)
+                    with self._stats_lock:
+                        self.stats.failed += 1
+        if span is not None:
             for req in bucket.requests:
-                req.future.set_exception(exc)
-                with self._stats_lock:
-                    self.stats.failed += 1
-        finally:
-            if span_cm is not None:
-                span_cm.__exit__(None, None, None)
+                tracer.record_span(
+                    "serve.request", req.enqueue_time,
+                    req.future.complete_time,
+                    queue_s=bucket.released - req.enqueue_time,
+                    dispatch=span.span_id, **self._labels)
 
     def pump(self, force: bool = False) -> int:
         """Dispatch at most one bucket now; returns requests served."""

@@ -160,6 +160,49 @@ def test_disabled_tracer_records_nothing():
     assert tr.finished() == []
 
 
+def test_annotate_mirrors_spans_in_lifo_order():
+    calls = []
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            calls.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            calls.append(("exit", self.name))
+
+    tr = Tracer(clock=FakeClock(), annotate=FakeAnnotation)
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+        with pytest.raises(RuntimeError):
+            with tr.span("boom"):
+                raise RuntimeError("x")
+    tr.event("not_mirrored")
+    tr.record_span("nor_this", 0.0, 1.0)
+    assert calls == [("enter", "outer"), ("enter", "inner"),
+                     ("exit", "inner"), ("enter", "boom"), ("exit", "boom"),
+                     ("exit", "outer")]
+    assert tr.open_spans() == []
+    with Tracer(enabled=False, annotate=FakeAnnotation).span("x") as s:
+        assert s is None
+    assert len(calls) == 6
+
+
+def test_trace_module_imports_no_jax():
+    import os
+    import subprocess
+    import sys
+
+    import repro
+    src = os.path.dirname(os.path.dirname(repro.__file__))
+    code = (f"import sys; sys.path.insert(0, {src!r}); "
+            "import repro.obs.trace; sys.exit('jax' in sys.modules)")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
+
+
 def test_span_stacks_are_thread_local():
     tr = Tracer(clock=FakeClock())
     seen = {}
