@@ -26,7 +26,7 @@ import time
 import warnings
 from contextlib import nullcontext
 from dataclasses import dataclass, field
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import numpy as np
@@ -44,6 +44,17 @@ _UNTRACED = nullcontext()
 
 def _no_phase(name: str) -> nullcontext:
     return _UNTRACED
+
+
+def _staging_buffer(shape, dtype) -> np.ndarray:
+    """A new host buffer of zeros with every page already faulted in.
+
+    ``np.zeros`` of a buffer this size maps pages that fault and zero on
+    first write; ``fill`` pays that once, here, and not in a dispatch.
+    """
+    buf = np.empty(shape, dtype)
+    buf.fill(0)
+    return buf
 
 
 class _Phases:
@@ -69,9 +80,10 @@ class _Phases:
         self._scope = self._tracer.span(name, **self._labels)
         return self
 
-    def __enter__(self) -> None:
+    def __enter__(self):
         self._span = self._scope.__enter__()
         self._span.t_start = self._t
+        return self._span
 
     def __exit__(self, *exc) -> bool:
         cpu = time.thread_time()
@@ -166,6 +178,18 @@ class SynthesisServer:
             "serving_dispatch_seconds",
             "Wall time of one bucket dispatch (pad + execute + scatter)",
             tuple(sorted(self._labels)))
+        self._staging_total = self.registry.counter(
+            "serving_staging_buffers_total",
+            "Bucket host buffers: the server's staging buffer reused, or "
+            "a buffer allocated", tuple(sorted(self._labels)) + ("outcome",))
+        for outcome in ("reused", "allocated"):
+            self._staging_total.inc(0, outcome=outcome, **self._labels)
+        # The staging buffer: one host buffer per server, as many rows as
+        # the largest bucket so far, written by the dispatch that holds
+        # the lock.  Rows past ``_staged_rows`` are zeros.
+        self._staging: Optional[np.ndarray] = None
+        self._staged_rows = 0
+        self._staging_lock = threading.Lock()
         self.stats = ServerStats()
         self._stats_lock = threading.Lock()   # submit() races the loop
         self._thread: Optional[threading.Thread] = None
@@ -202,16 +226,26 @@ class SynthesisServer:
         stole) itself; the bucket need not come from this server's own
         batcher — work stealing dispatches a peer's requests here.
 
+        A bucket of more than one image is copied into the server's
+        staging buffer (:meth:`_assemble`), which is written again only
+        once the previous dispatch's output is ready, so the device no
+        longer reads it; ``x_dev``, which may alias it on the CPU, is not
+        read after that.  A dispatch that finds the buffer held by
+        another thread, or that must grow it, allocates one, as does a
+        bucket of one; a dispatch that raises gives the buffer up.
+
         Traced, the ``serve.dispatch`` span holds six back-to-back child
         spans ``serve.dispatch.<phase>``, each with ``cpu_s``: ``lookup``
-        (the cache, fingerprint included), ``assemble`` (the host
-        buffer), ``transfer`` (``device_put`` as the host sees it),
-        ``execute`` (the call to ``block_until_ready``, with whatever of
-        the copy to the device is still in flight), ``copy_out``
-        (``devices()`` and ``np.asarray``) and ``complete`` (histogram,
-        stats, futures).  Then each request gets a ``serve.request``
-        span, enqueue to result, with ``queue_s`` (enqueue to release)
-        and ``dispatch`` (the ``span_id`` of the span that served it).
+        (the cache, fingerprint included), ``assemble`` (the copy into
+        the host buffer; ``staged`` is 1 where the staging buffer was
+        reused, 0 where a buffer was allocated), ``transfer``
+        (``device_put`` as the host sees it), ``execute`` (the call to
+        ``block_until_ready``, with whatever of the copy to the device
+        is still in flight), ``copy_out`` (``devices()`` and
+        ``np.asarray``) and ``complete`` (histogram, stats, futures).
+        Then each request gets a ``serve.request`` span, enqueue to
+        result, with ``queue_s`` (enqueue to release) and ``dispatch``
+        (the ``span_id`` of the span that served it).
         """
         t0 = self.registry.clock()
         tracer = self.tracer
@@ -221,16 +255,20 @@ class SynthesisServer:
                           **self._labels)) as span:
             phase = _no_phase if span is None else \
                 _Phases(tracer, span, self._labels)
+            # Never wait for the buffer: a racing pump() allocates its own.
+            # A bucket of one stays on a fresh buffer: on a v5e host the
+            # staging buffer there lengthened one-image latency (PERF.md
+            # §6) to save ~0.05 ms.
+            held = bucket.batch > 1 and \
+                self._staging_lock.acquire(blocking=False)
             try:
                 with phase("serve.dispatch.lookup"):
                     compiled = self.cache.get_or_build(
                         self.program, bucket.batch, self.device)
-                with phase("serve.dispatch.assemble"):
-                    # One host buffer per bucket (zero rows pad it).
-                    x = np.zeros((bucket.batch, *self.program.net.input_shape),
-                                 self.program.input_dtype)
-                    for i, r in enumerate(bucket.requests):
-                        x[i] = r.image
+                with phase("serve.dispatch.assemble") as assemble:
+                    x, staged = self._assemble(bucket, held)
+                    if assemble is not None:
+                        assemble.attrs["staged"] = int(staged)
                 with phase("serve.dispatch.transfer"):
                     x_dev = jax.device_put(x, self.device)
                 with phase("serve.dispatch.execute"):
@@ -254,12 +292,17 @@ class SynthesisServer:
                         with self._stats_lock:
                             self.stats.completed += 1
             except Exception as exc:  # surface the failure on every request
+                if held:              # the device may still read it
+                    self._staging = None
                 if span is not None:
                     span.attrs["error"] = True
                 for req in bucket.requests:
                     req.future.set_exception(exc)
                     with self._stats_lock:
                         self.stats.failed += 1
+            finally:
+                if held:
+                    self._staging_lock.release()
         if span is not None:
             for req in bucket.requests:
                 tracer.record_span(
@@ -267,6 +310,35 @@ class SynthesisServer:
                     req.future.complete_time,
                     queue_s=bucket.released - req.enqueue_time,
                     dispatch=span.span_id, **self._labels)
+
+    def _assemble(self, bucket: Bucket, held: bool
+                  ) -> Tuple[np.ndarray, bool]:
+        """The bucket's host input, its images then zero rows, and
+        whether it is the reused staging buffer.
+
+        ``held``: this dispatch holds the staging lock.  Only rows the
+        last bucket wrote past this bucket's requests are zeroed, so a
+        full bucket writes its rows and nothing else.
+        """
+        n, batch = len(bucket.requests), bucket.batch
+        buf = self._staging if held else None
+        staged = buf is not None and len(buf) >= batch
+        if staged:
+            buf[n:self._staged_rows] = 0
+        elif held:
+            buf = self._staging = _staging_buffer(
+                (batch, *self.program.net.input_shape),
+                self.program.input_dtype)
+        else:
+            buf = np.zeros((batch, *self.program.net.input_shape),
+                           self.program.input_dtype)
+        self._staging_total.inc(outcome="reused" if staged else "allocated",
+                                **self._labels)
+        for i, r in enumerate(bucket.requests):
+            buf[i] = r.image
+        if held:
+            self._staged_rows = n
+        return buf[:batch], staged
 
     def pump(self, force: bool = False) -> int:
         """Dispatch at most one bucket now; returns requests served."""

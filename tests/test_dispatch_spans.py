@@ -8,11 +8,19 @@
 * untraced, the dispatch records nothing, reads no extra clock, and
   returns bitwise what the traced dispatch returns;
 * with ``Tracer(annotate=jax.profiler.TraceAnnotation)`` the spans land
-  in a profiler capture, nested on the dispatch thread's line.
+  in a profiler capture, nested on the dispatch thread's line;
+* ``assemble`` writes the server's staging buffer where it can (``staged``
+  on the span, ``serving_staging_buffers_total`` in the registry): pad
+  rows stay zero, served rows stay as they were when the buffer is
+  written again, a failed dispatch gives the buffer up, and a dispatch
+  racing the holder allocates its own.
 """
 import collections
 import glob
 import statistics
+import sys
+import threading
+import time
 
 import jax
 import numpy as np
@@ -21,7 +29,8 @@ import pytest
 from repro.cnn import init_network_params, squeezenet
 from repro.core import ComputeMode, synthesize
 from repro.obs import MetricsRegistry, Tracer
-from repro.serving import ServingConfig, SynthesisServer
+from repro.serving import ServingConfig, ServingFuture, SynthesisServer
+from repro.serving.batcher import Bucket, Request
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -174,3 +183,184 @@ def test_profiler_capture_holds_mirrored_dispatch_phases(program, images,
         diffs[e.name].append(abs(e.duration_ns / 1e9 - s.duration_s))
     for name, d in diffs.items():
         assert statistics.median(d) < 100e-6, name
+
+
+# ---------------------------------------------------------- staging buffer ---
+@pytest.fixture(scope="module")
+def alone(program, images):
+    """Each image run through the program by itself, a bucket of 1."""
+    one = program.for_batch(1)
+    return np.stack([np.asarray(one(im[None]))[0] for im in images])
+
+
+def _bucket(images, batch):
+    """A bucket of ``batch`` slots holding one request per image."""
+    now = time.perf_counter()
+    return Bucket(requests=[Request(im, ServingFuture(), now)
+                            for im in images], batch=batch, released=now)
+
+
+def _rows(bucket):
+    return np.stack([r.future.result(timeout=30.0) for r in bucket.requests])
+
+
+def _staging(server, **labels):
+    total = server.registry.get("serving_staging_buffers_total")
+    return {o: total.value(outcome=o, **labels)
+            for o in ("reused", "allocated")}
+
+
+def _aligned(shape, dtype):
+    """Zeros on a page boundary, so the CPU's ``device_put`` may alias
+    them rather than copy."""
+    size = int(np.prod(shape)) * np.dtype(dtype).itemsize
+    raw = np.zeros(size + 4096, np.uint8)
+    start = -raw.ctypes.data % 4096
+    return raw[start:start + size].view(dtype).reshape(shape)
+
+
+@pytest.mark.parametrize("counts, staged", [
+    ((4, 4, 4), [0, 1, 1]),        # one buffer for every full bucket
+    ((1, 4, 2), [0, 0, 1]),        # grows for the 4, then reused
+    ((4, 3, 2), [0, 1, 1]),        # smaller buckets pad inside it
+    ((4, 1, 4), [0, 0, 1]),        # a bucket of one takes a fresh buffer
+])
+def test_staging_counts_and_span_attribute(program, images, alone, counts,
+                                           staged):
+    tracer = Tracer()
+    server = _server(program, tracer=tracer, labels={"replica": 2})
+    start = 0
+    for n in counts:
+        bucket = _bucket(images[start:start + n], 1 << (n - 1).bit_length())
+        server.dispatch_bucket(bucket)
+        np.testing.assert_array_equal(_rows(bucket),
+                                      alone[start:start + n])
+        start += n
+    spans = tracer.by_name("serve.dispatch.assemble")
+    assert [s.attrs["staged"] for s in spans] == staged
+    assert _staging(server, replica=2) == {
+        "reused": sum(staged), "allocated": len(staged) - sum(staged)}
+
+
+def test_staging_pads_with_zeros_after_a_larger_bucket(program, images,
+                                                       alone, monkeypatch):
+    import repro.serving.server as server_mod
+
+    sent = []
+    put = server_mod.jax.device_put
+
+    def spy(x, device=None):
+        sent.append(np.array(x))
+        return put(x, device)
+
+    monkeypatch.setattr(server_mod.jax, "device_put", spy)
+    server = _server(program)
+    full, one = _bucket(images[:4], 4), _bucket(images[4:5], 4)
+    server.dispatch_bucket(full)
+    server.dispatch_bucket(one)
+    assert _staging(server) == {"reused": 1, "allocated": 1}
+    np.testing.assert_array_equal(sent[1][0], images[4])
+    assert not sent[1][1:].any()               # the old rows were zeroed
+    np.testing.assert_array_equal(_rows(full), alone[:4])
+    np.testing.assert_array_equal(_rows(one), alone[4:5])
+
+
+def test_served_rows_survive_the_next_bucket_in_an_aliased_buffer(
+        program, images, alone, monkeypatch):
+    import repro.serving.server as server_mod
+
+    monkeypatch.setattr(server_mod, "_staging_buffer", _aligned)
+    server = _server(program)
+    buckets = [_bucket(images[i:i + 4], 4) for i in (0, 4, 8)]
+    server.dispatch_bucket(buckets[0])
+    assert server._staging.ctypes.data % 4096 == 0
+    first = _rows(buckets[0]).copy()
+    for b in buckets[1:]:
+        server.dispatch_bucket(b)
+    assert _staging(server) == {"reused": 2, "allocated": 1}
+    np.testing.assert_array_equal(_rows(buckets[0]), first)
+    np.testing.assert_array_equal(
+        np.concatenate([_rows(b) for b in buckets]), alone)
+
+
+@pytest.mark.parametrize("where", ["assemble", "execute"])
+def test_failed_dispatch_gives_the_staging_buffer_up(program, images, alone,
+                                                     monkeypatch, where):
+    server = _server(program)
+    server.dispatch_bucket(_bucket(images[:4], 4))
+    if where == "assemble":                     # a row that cannot be copied
+        bad = _bucket([images[4], images[5][:, :8]], 2)
+    else:
+        def lost(*args):
+            def call(x):
+                raise RuntimeError("device lost")
+            return call
+
+        monkeypatch.setattr(server.cache, "get_or_build", lost)
+        bad = _bucket(images[4:6], 2)
+    server.dispatch_bucket(bad)
+    monkeypatch.undo()
+    for r in bad.requests:
+        with pytest.raises(Exception):
+            r.future.result(timeout=30.0)
+    good = _bucket(images[6:10], 4)
+    server.dispatch_bucket(good)
+    np.testing.assert_array_equal(_rows(good), alone[6:10])
+    # the buffer went with the failure: the next bucket allocated anew
+    assert _staging(server) == {"reused": 1, "allocated": 2}
+    assert server.stats.failed == 2
+
+
+def test_racing_dispatch_allocates_its_own_buffer(program, images, alone,
+                                                  monkeypatch):
+    server = _server(program)
+    server.dispatch_bucket(_bucket(images[:4], 4))
+    build = server.cache.get_or_build
+    both = threading.Barrier(2, timeout=30.0)
+
+    def meeting(*args):
+        compiled = build(*args)
+
+        def call(x):
+            both.wait()         # each executes while the other holds a buffer
+            return compiled(x)
+        return call
+
+    monkeypatch.setattr(server.cache, "get_or_build", meeting)
+    buckets = [_bucket(images[i:i + 4], 4) for i in (4, 8)]
+    threads = [threading.Thread(target=server.dispatch_bucket, args=(b,))
+               for b in buckets]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60.0)
+    assert not any(t.is_alive() for t in threads)
+    assert _staging(server) == {"reused": 1, "allocated": 2}
+    np.testing.assert_array_equal(
+        np.concatenate([_rows(b) for b in buckets]), alone[4:12])
+
+
+def test_staging_under_many_dispatching_threads(program, images, alone):
+    server = _server(program)
+    jobs = [(i, n) for i in range(8) for n in (1, 3, 4, 2)]
+    buckets = [_bucket(images[(i * 3) % 8:(i * 3) % 8 + n],
+                       1 << (n - 1).bit_length()) for i, n in jobs]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(
+            target=lambda k=k: [server.dispatch_bucket(b)
+                                for b in buckets[k::8]]) for k in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120.0)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    for (i, n), b in zip(jobs, buckets):
+        np.testing.assert_array_equal(
+            _rows(b), alone[(i * 3) % 8:(i * 3) % 8 + n])
+    counts = _staging(server)
+    assert counts["reused"] + counts["allocated"] == len(buckets)
+    assert server.stats.completed == sum(n for _, n in jobs)
