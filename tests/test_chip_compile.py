@@ -25,6 +25,7 @@ from repro.core.precision import ComputeMode
 from repro.kernels.conv_mapmajor import ops as conv_ops
 from repro.kernels.conv_mapmajor.conv_mapmajor import (conv_mapmajor,
                                                        conv_mapmajor_int8)
+from repro.kernels.matmul_mapmajor import ops as matmul_ops
 from repro.kernels.matmul_mapmajor.matmul_mapmajor import (
     matmul_mapmajor, matmul_mapmajor_int8)
 
@@ -72,7 +73,11 @@ def _compile_conv(sharding, *, h, cin, cout, k, stride=1, int8=False,
     (27, 64, 256, 3),      # SqueezeNet fire8_expand3x3: 2 output groups
     (27, 96, 256, 5),      # AlexNet conv2
     (13, 256, 384, 3),     # AlexNet conv3: 2 input x 3 output groups
-], ids=["fire8_expand3x3", "alexnet_conv2", "alexnet_conv3"])
+    (56, 64, 192, 3),      # GoogLeNet conv2: the largest plane, 2 out groups
+    (28, 16, 32, 5),       # GoogLeNet inc3a_5x5: 16 of 128 lanes in, 32 out
+    (7, 192, 384, 3),      # GoogLeNet inc5b_3x3: 2 input x 3 output groups
+], ids=["fire8_expand3x3", "alexnet_conv2", "alexnet_conv3",
+        "googlenet_conv2", "googlenet_inc3a_5x5", "googlenet_inc5b_3x3"])
 def test_fused_conv_multi_group_compiles(one_chip, h, cin, cout, k, int8):
     _compile_conv(one_chip, h=h, cin=cin, cout=cout, k=k, int8=int8)
 
@@ -114,5 +119,25 @@ def test_fc6_matmul_compiles(one_chip, int8):
                  sharding=one_chip)
     else:
         _compile(lambda a, b: matmul_mapmajor(a, b, interpret=False),
+                 ((m, kdim), jnp.bfloat16), ((kdim, n), jnp.bfloat16),
+                 sharding=one_chip)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_googlenet_fc_padded_matmul_compiles(one_chip, int8):
+    """GoogLeNet fc: (32, 1024) @ (1024, 1000).  N = 1000 is no multiple
+    of the 256-wide block, so the program runs it through the padding
+    wrappers (the raw kernel refuses it)."""
+    m, kdim, n = 32, 1024, 1000
+    blocks = dict(bm=256, bn=256, bk=512, interpret=False)
+    if int8:
+        _compile(lambda a, w, ws, s, b: matmul_ops._matmul_padded_int8(
+                     a, w, ws, s, b, relu=False, **blocks),
+                 ((m, kdim), jnp.float32), ((kdim, n), jnp.int8),
+                 ((n,), jnp.float32), ((), jnp.float32),
+                 ((n,), jnp.float32), sharding=one_chip)
+    else:
+        _compile(lambda a, w: matmul_ops._matmul_padded(
+                     a, w, ComputeMode.RELAXED, **blocks),
                  ((m, kdim), jnp.bfloat16), ((kdim, n), jnp.bfloat16),
                  sharding=one_chip)
