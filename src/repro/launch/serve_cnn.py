@@ -18,7 +18,8 @@ and a metrics snapshot rendered from the tier's registry
 (``DIR/plugins/profile/<time>/*.xplane.pb``, for TensorBoard, Perfetto or
 ``jax.profiler.ProfileData``) with the tracer's spans mirrored into it
 through ``jax.profiler.TraceAnnotation``: each ``serve.dispatch`` and its
-``serve.dispatch.<phase>`` children sit on the dispatch thread's line,
+``serve.dispatch.<phase>`` children sit on the line of the thread that ran
+them (a pipelined bucket's on the serving thread's and its completer's),
 on the same clock as the device's operations.
 
 ``--artifact-dir PATH`` attaches a persistent

@@ -106,9 +106,11 @@ class Tracer:
         return stack
 
     def _new_span(self, name: str, t_start: float,
-                  attrs: Dict[str, object]) -> Span:
-        stack = self._stack()
-        parent = stack[-1].span_id if stack else None
+                  attrs: Dict[str, object],
+                  parent: Optional[int] = None) -> Span:
+        if parent is None:
+            stack = self._stack()
+            parent = stack[-1].span_id if stack else None
         with self._lock:
             self._next_id += 1
             sid = self._next_id
@@ -141,14 +143,20 @@ class Tracer:
         return s
 
     def record_span(self, name: str, t_start: float, t_end: float,
+                    parent: Optional[int] = None,
                     **attrs) -> Optional[Span]:
         """Record a span from caller-supplied timestamps (same clock base
         as ``tracer.clock``).  Used for retroactive regions whose start
         predates the recording call — e.g. the batcher's enqueue→flush
-        wait, whose start is the oldest request's enqueue time."""
+        wait, whose start is the oldest request's enqueue time.
+
+        ``parent`` is the ``span_id`` of the span's parent; by default,
+        the span open on the calling thread, as for :meth:`span`.  A
+        region that ran on another thread, such as a phase of a
+        pipelined dispatch, names its parent here."""
         if not self.enabled:
             return None
-        s = self._new_span(name, t_start, attrs)
+        s = self._new_span(name, t_start, attrs, parent)
         self._finish(s, t_end)
         return s
 

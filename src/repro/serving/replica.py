@@ -33,13 +33,16 @@ different device profiles (the plan fingerprint covers the profile
 identity), never alias.
 
 Like the single server, the tier is dual-mode: ``start()``/``stop()`` run
-one dispatch thread per replica; ``pump()``/``drain()`` are hand-pumped
-and deterministic for tests.
+one serving thread per replica, each with up to two buckets in flight
+(:meth:`~repro.serving.server.SynthesisServer.serve`); ``pump()``/
+``drain()`` are hand-pumped, one bucket at a time, and deterministic for
+tests.
 """
 from __future__ import annotations
 
 import threading
 import time
+from functools import partial
 from typing import Dict, List, Optional, Sequence, Union
 
 import jax
@@ -311,24 +314,16 @@ class ReplicaSet:
             served += n
 
     # -- background loops ---------------------------------------------------
-    def _loop(self, i: int) -> None:
-        srv = self.replicas[i].server
-        poll = max(self.config.max_delay_s, 1e-4)
-        while not self._stopping.is_set():
-            bucket = self._take_for(i)
-            if bucket is not None:
-                srv.dispatch_bucket(bucket)
-                continue
-            srv.wait_for_trigger(self._stopping, poll)
-
     def start(self) -> "ReplicaSet":
         if self._threads:
             raise RuntimeError("replica set already started")
         self._stopping.clear()
         self._threads = [
-            threading.Thread(target=self._loop, args=(i,),
+            threading.Thread(target=r.server.serve,
+                             args=(partial(self._take_for, i),
+                                   self._stopping),
                              name=f"replica-{i}", daemon=True)
-            for i in range(len(self.replicas))]
+            for i, r in enumerate(self.replicas)]
         for t in self._threads:
             t.start()
         return self

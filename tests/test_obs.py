@@ -151,6 +151,27 @@ def test_event_and_record_span():
                                                "serve.batch_wait"}
 
 
+def test_record_span_takes_the_open_span_or_an_explicit_parent():
+    tr = Tracer(clock=FakeClock())
+    with tr.span("outer") as outer:
+        nested = tr.record_span("nested", 1.0, 2.0)
+    root = tr.record_span("root", 0.0, 3.0)
+    child = tr.record_span("child", 1.0, 2.0, parent=root.span_id,
+                           phase="x")
+    assert nested.parent_id == outer.span_id
+    assert root.parent_id is None
+    assert child.parent_id == root.span_id and child.attrs == {"phase": "x"}
+
+    seen = []
+    other = threading.Thread(target=lambda: seen.append(
+        tr.record_span("from_another_thread", 1.0, 2.0,
+                       parent=outer.span_id)))
+    other.start()
+    other.join(timeout=10.0)
+    assert not other.is_alive()
+    assert seen[0].parent_id == outer.span_id
+
+
 def test_disabled_tracer_records_nothing():
     tr = Tracer(enabled=False)
     with tr.span("x") as s:
